@@ -37,10 +37,10 @@ from repro.check.diagnostics import (CODES, CheckError, CodeInfo, Diagnostic,
                                      raise_on_error, render_all)
 from repro.check.footprint import (KernelTrace, UntraceableKernel,
                                    trace_launch, visit_structure)
-from repro.check.kernels import (LaunchSpec, OperandSpec, check_conv_launch,
-                                 check_flash_launch, check_launch,
-                                 check_matmul_launch, check_network_kernels,
-                                 flash_launch, preflight_flash_launch,
+from repro.check.kernels import (check_conv_launch, check_flash_launch,
+                                 check_launch, check_matmul_launch,
+                                 check_network_kernels,
+                                 preflight_flash_launch,
                                  preflight_network_kernels)
 from repro.check.lint import (LintRule, default_rules, lint_file, lint_repo,
                               load_rules)
@@ -54,8 +54,8 @@ __all__ = [
     "check", "verify", "summarize",
     "check_workload", "check_schedule", "check_traffic", "check_plan",
     "check_graph", "check_netplan",
-    "LaunchSpec", "OperandSpec", "check_launch", "check_conv_launch",
-    "check_matmul_launch", "check_flash_launch", "flash_launch",
+    "check_launch", "check_conv_launch",
+    "check_matmul_launch", "check_flash_launch",
     "check_network_kernels",
     "preflight_network_kernels", "preflight_flash_launch",
     "LintRule", "default_rules", "load_rules", "lint_file", "lint_repo",

@@ -583,39 +583,74 @@ def conv_dataflow(wl: ConvWorkload, schedule: Schedule,
 
 
 # ---------------------------------------------------------- matmul kernel
+def _sum_words(parts: Sequence[RefWords]) -> RefWords:
+    """One ref's words summed over the launches of a multi-launch kernel."""
+    return RefWords(
+        name=parts[0].name,
+        compute_reads=sum(p.compute_reads for p in parts),
+        compute_writes=sum(p.compute_writes for p in parts),
+        hbm_reads=sum(p.hbm_reads for p in parts),
+        hbm_writes=sum(p.hbm_writes for p in parts),
+        hbm_model=sum(p.hbm_model for p in parts),
+        hbm_equal=all(p.hbm_equal for p in parts))
+
+
 def matmul_dataflow(wl: MatmulWorkload, schedule: Schedule,
                     subject: Optional[str] = None) -> DataflowReport:
     """Prove `psum_matmul` under ``schedule`` moves exactly the words
-    `gemm_model.matmul_traffic` charges, for either controller."""
+    `gemm_model.matmul_traffic` charges, for either controller.
+
+    The passive GEMM is one launch per k-step; every launch is traced and
+    the words summed. Its partial sums are the step-0 output plus, at each
+    later step, an input read back from HBM and aliased to the output — so
+    their (gk, gk - 1) write/read chain is counted from real HBM transfers,
+    never from output read-backs (Pallas issues none)."""
     from repro.check.kernels import check_matmul_launch
     from repro.plan.gemm_model import matmul_traffic
-    subject = subject or f"dataflow/{wl.name}/{schedule.controller.value}"
+    ctrl = schedule.controller.value
+    subject = subject or f"dataflow/{wl.name}/{ctrl}"
     geo = check_matmul_launch(wl.m, wl.k, wl.n, schedule, subject)
     if errors(geo):
         return DataflowReport(subject, tuple(geo), {})
-    from repro.kernels.psum_matmul import matmul_launch_plan
-    plan = matmul_launch_plan(m=wl.m, k=wl.k, n=wl.n, bm=schedule.bm,
-                              bn=schedule.bn, bk=schedule.bk,
-                              controller=schedule.controller.value)
-    diags, ana = analyze_launch(plan, subject)
-    if ana is None or errors(diags):
-        return DataflowReport(subject, tuple(geo + diags), {})
+    from repro.kernels.psum_matmul import (matmul_launch_plan,
+                                           reduction_launches)
     model = matmul_traffic(wl.m, wl.n, wl.k, schedule, schedule.controller)
     acc_real = wl.m * wl.n
-    try:
-        words = {
-            "x": _in_words(ana, "x", wl.m * wl.k),
-            "w": _in_words(ana, "w", wl.k * wl.n),
-            "out": _out_words(ana, "out", acc_real),
-        }
-        if schedule.controller is Controller.ACTIVE:
-            acc_w, acc_r = _scratch_chain(ana, "acc", acc_real)
-        else:   # the output ref *is* the accumulator (psums round-trip HBM)
-            acc_w = words["out"].compute_writes
-            acc_r = words["out"].compute_reads
-    except _Unsupported as exc:
-        diags.append(Diagnostic("RPC046", subject, str(exc)))
-        return DataflowReport(subject, tuple(geo + diags), {})
+    gk = math.ceil(wl.k / schedule.bk)
+    diags: List[Diagnostic] = []
+    parts: Dict[str, List[RefWords]] = {}
+    acc_w = acc_r = 0
+    for step in range(reduction_launches(wl.k, schedule.bk, ctrl)):
+        plan = matmul_launch_plan(m=wl.m, k=wl.k, n=wl.n, bm=schedule.bm,
+                                  bn=schedule.bn, bk=schedule.bk,
+                                  controller=ctrl, k_step=step)
+        d, ana = analyze_launch(plan, subject)
+        diags += d
+        if ana is None or errors(d):
+            return DataflowReport(subject, tuple(geo + diags), {})
+        # Real words a launch touches: all of K when active, its k-slab
+        # when passive.
+        k_real = wl.k if ctrl == "active" else \
+            min(wl.k, (step + 1) * schedule.bk) - step * schedule.bk
+        try:
+            parts.setdefault("x", []).append(
+                _in_words(ana, "x", wl.m * k_real))
+            parts.setdefault("w", []).append(
+                _in_words(ana, "w", k_real * wl.n))
+            out = _out_words(ana, "out", acc_real)
+            parts.setdefault("out", []).append(out)
+            if ctrl == "active":
+                acc_w, acc_r = _scratch_chain(ana, "acc", acc_real)
+            else:
+                acc_w += out.compute_writes
+                if step:
+                    psums = _in_words(ana, "psums", acc_real)
+                    parts.setdefault("psums", []).append(psums)
+                    acc_r += psums.compute_reads
+        except _Unsupported as exc:
+            diags.append(Diagnostic("RPC046", subject, str(exc)))
+            return DataflowReport(subject, tuple(geo + diags), {})
+    words = {name: _sum_words(p) for name, p in parts.items()}
     if words["x"].compute_reads != int(model["a_reads"]):
         diags.append(_mismatch(subject, "A reads vs x loads",
                                words["x"].compute_reads,
@@ -624,19 +659,20 @@ def matmul_dataflow(wl: MatmulWorkload, schedule: Schedule,
         diags.append(_mismatch(subject, "B reads vs w loads",
                                words["w"].compute_reads,
                                int(model["b_reads"])))
-    if schedule.controller is Controller.ACTIVE:
-        c_derived = words["out"].hbm_writes + words["out"].hbm_reads
+    hbm_c = words["out"].hbm_writes + words["out"].hbm_reads
+    if ctrl == "active":
+        c_derived = hbm_c
     else:
         c_derived = acc_w + acc_r
-        hbm_c = words["out"].hbm_writes + words["out"].hbm_reads
-        if hbm_c > c_derived:
+        if "psums" in words:
+            hbm_c += words["psums"].hbm_reads
+        if hbm_c != c_derived:
             diags.append(_mismatch(
-                subject, "passive C: HBM round-trips exceed the RMW chain",
+                subject, "passive C: HBM round-trips vs the RMW chain",
                 hbm_c, c_derived))
     if c_derived != int(model["c_traffic"]):
         diags.append(_mismatch(subject, "C traffic vs accumulator RMW",
                                c_derived, int(model["c_traffic"])))
-    gk = math.ceil(wl.k / schedule.bk)
     if (acc_w, acc_r) != (gk * acc_real, (gk - 1) * acc_real):
         diags.append(Diagnostic(
             "RPC043", subject,
@@ -657,9 +693,10 @@ def flash_dataflow(bh: int, sq: int, skv: int, d: int, bq: int = 128,
                    subject: str = "dataflow/flash_attention"
                    ) -> DataflowReport:
     """Pin `flash_attention`'s traffic to its closed form: Q and O cross HBM
-    once, K/V once per q block, and the softmax state (acc, m, l) does the
-    (L, L-1) VMEM RMW chain over kv blocks — the attention analogue of the
-    paper's active accumulation."""
+    once, K/V once per q block — or once in all when the kv axis has a
+    single block, whose index then never changes across the q blocks — and
+    the softmax state (acc, m, l) does the (L, L-1) VMEM RMW chain over kv
+    blocks: the attention analogue of the paper's active accumulation."""
     from repro.check.kernels import check_flash_launch
     geo = check_flash_launch(bh, sq, skv, d, bq, bk, causal, q_offset,
                              subject)
@@ -684,10 +721,13 @@ def flash_dataflow(bh: int, sq: int, skv: int, d: int, bq: int = 128,
     except _Unsupported as exc:
         diags.append(Diagnostic("RPC046", subject, str(exc)))
         return DataflowReport(subject, tuple(geo + diags), {})
+    # K/V visit (head, kv block): they are re-fetched for each q block only
+    # while the kv axis, their innermost visit axis, actually moves.
+    kv_fetches = gq if gk > 1 else 1
     expect = {
         "q hbm": (words["q"].hbm_reads, q_real),
-        "k hbm": (words["k"].hbm_reads, gq * kv_real),
-        "v hbm": (words["v"].hbm_reads, gq * kv_real),
+        "k hbm": (words["k"].hbm_reads, kv_fetches * kv_real),
+        "v hbm": (words["v"].hbm_reads, kv_fetches * kv_real),
         "out hbm": (words["out"].hbm_writes + words["out"].hbm_reads,
                     o_real),
         "softmax-state RMW": ((acc_w, acc_r),
@@ -847,7 +887,7 @@ def certify_matmul_space(wl: MatmulWorkload, budget: Optional[int] = None,
     acc = wl.m * wl.n
     if controller is Controller.ACTIVE:
         c_d = np.full_like(a_d, float(acc))
-    else:
+    else:     # gk launches: gk psum writes, gk - 1 read-backs, all via HBM
         c_d = ((2 * gk - 1) * acc).astype(np.float64)
     for name, dv, mv in (("A reads", a_d, t["a_reads"]),
                          ("B reads", b_d, t["b_reads"]),
@@ -865,10 +905,10 @@ def certify_matmul_space(wl: MatmulWorkload, budget: Optional[int] = None,
         x_eq = (gk > 1) | (gj == 1)           # x block (i, kk) vs inner j
         w_eq = (gj > 1) | (gk > 1) | (gi == 1)
         c_eq = np.ones_like(x_eq, dtype=bool)  # out crosses HBM once = model
-    else:                                     # grid (gk, gm, gn)
+    else:                                     # gk launches, grid (gm, gn)
         x_eq = (gj == 1)                      # x block (i, kk) constant in j
         w_eq = (gj > 1) | (gi == 1)           # w block (kk, j) re-fetched/i
-        c_eq = (gi > 1) | (gj > 1) | (gk == 1)  # else psums stay in VMEM
+        c_eq = np.ones_like(x_eq, dtype=bool)  # every psum crosses HBM
     full_eq = x_eq & w_eq & c_eq
     return SpaceCertificate(
         subject, "matmul", controller.value, int(bm.size),

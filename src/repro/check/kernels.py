@@ -1,33 +1,29 @@
 """Pallas launch pre-flight: prove a kernel's BlockSpec geometry before
 anything compiles.
 
-`conv2d_psum` / `psum_matmul` pick their grid, BlockSpecs, and scratch from a
-`Schedule`; a malformed launch (block not dividing the padded array, an index
-map addressing past the array, a VMEM working set over budget) surfaces from
-Mosaic as a deep compile error — or worse, as silent padding garbage under
-``interpret=True``. This module re-derives the exact launch geometry the
-kernels build (same clamping, same padding) as plain integers and checks it
-statically, so `run_network_kernels` can reject a bad plan with an RPC03x
+`conv2d_psum` / `psum_matmul` / `flash_attention` pick their grid,
+BlockSpecs, and scratch from a `Schedule`; a malformed launch (block not
+dividing the padded array, an index map addressing past the array, a VMEM
+working set over the launch's limit) surfaces from Mosaic as a deep compile
+error — or worse, as silent padding garbage under the interpreter. This
+module checks the very `repro.kernels.launch.LaunchPlan` each kernel executes
+(built from plain integers by the kernel's ``*_launch_plan`` builder, imported
+lazily), so `run_network_kernels` can reject a bad plan with an RPC03x
 diagnostic *before* the first `pallas_call`.
-
-The geometry here must mirror ``repro.kernels.conv2d_psum`` /
-``repro.kernels.psum_matmul``; the pin tests in ``tests/test_check.py`` run
-both and assert the checker admits exactly what the kernels execute.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import itertools
-from typing import Any, Callable, List, Mapping, Optional, Sequence, Tuple
+from typing import Any, List, Mapping, Optional, Sequence
+
+import numpy as np
 
 from repro.check.diagnostics import Diagnostic, errors, raise_on_error
-from repro.plan.gemm_model import VMEM_BYTES
+from repro.plan.gemm_model import VMEM_LIMIT_BYTES
 from repro.plan.graph import NetworkGraph
 from repro.plan.schedule import Schedule
 from repro.plan.workload import ConvWorkload
-
-IndexMap = Callable[..., Tuple[int, ...]]
 
 # Grids with at most this many points get every point's index map evaluated;
 # larger grids are sampled at the corners (sound for the kernels' affine
@@ -35,40 +31,7 @@ IndexMap = Callable[..., Tuple[int, ...]]
 _EXHAUSTIVE_GRID = 4096
 
 
-@dataclasses.dataclass(frozen=True)
-class OperandSpec:
-    """One pallas_call operand: its full (padded) array and its BlockSpec."""
-
-    name: str
-    array_shape: Tuple[int, ...]
-    block_shape: Tuple[int, ...]
-    index_map: IndexMap
-    elem_bytes: int = 4
-
-    @property
-    def block_bytes(self) -> int:
-        n = self.elem_bytes
-        for d in self.block_shape:
-            n *= d
-        return n
-
-
-@dataclasses.dataclass(frozen=True)
-class LaunchSpec:
-    """A complete launch description: grid + operands + scratch, checkable
-    without touching jax."""
-
-    subject: str
-    grid: Tuple[int, ...]
-    operands: Tuple[OperandSpec, ...]
-    scratch_bytes: int = 0
-
-    @property
-    def vmem_bytes(self) -> int:
-        return sum(op.block_bytes for op in self.operands) + self.scratch_bytes
-
-
-def _grid_points(grid: Tuple[int, ...]):
+def _grid_points(grid: Sequence[int]):
     total = 1
     for g in grid:
         total *= g
@@ -80,88 +43,71 @@ def _grid_points(grid: Tuple[int, ...]):
     return itertools.product(*ranges)
 
 
-def check_launch(launch: LaunchSpec,
-                 vmem_budget: Optional[int] = None) -> List[Diagnostic]:
-    """RPC030 (divisibility), RPC031 (index map range / rank), RPC032 (VMEM)."""
+def launch_vmem_bytes(plan: Any) -> int:
+    """VMEM a launch allocates: Pallas double-buffers every blocked operand;
+    scratch buffers are allocated once."""
+    blocks = sum(op.block_words * op.elem_bytes for op in plan.operands)
+    scratch = sum(s.words * np.dtype(s.dtype or np.float32).itemsize
+                  for s in plan.scratch)
+    return 2 * blocks + scratch
+
+
+def check_launch(plan: Any, vmem_budget: Optional[int] = None,
+                 subject: Optional[str] = None) -> List[Diagnostic]:
+    """RPC030 (divisibility), RPC031 (index map range / rank), RPC032 (VMEM)
+    for a `LaunchPlan` (or anything with its name/grid/operands/scratch)."""
     out: List[Diagnostic] = []
-    budget = VMEM_BYTES if vmem_budget is None else int(vmem_budget)
-    if any(g < 1 for g in launch.grid):
+    subject = subject or plan.name
+    budget = VMEM_LIMIT_BYTES if vmem_budget is None else int(vmem_budget)
+    if any(g < 1 for g in plan.grid):
         out.append(Diagnostic(
-            "RPC031", launch.subject, f"empty grid {launch.grid}"))
+            "RPC031", subject, f"empty grid {plan.grid}"))
         return out
-    for op in launch.operands:
+    for op in plan.operands:
         if len(op.block_shape) != len(op.array_shape):
             out.append(Diagnostic(
-                "RPC031", launch.subject,
+                "RPC031", subject,
                 f"{op.name}: block rank {len(op.block_shape)} != array rank "
                 f"{len(op.array_shape)}"))
             continue
         if any(b < 1 for b in op.block_shape):
             out.append(Diagnostic(
-                "RPC030", launch.subject,
+                "RPC030", subject,
                 f"{op.name}: non-positive block {op.block_shape}"))
             continue
         if any(a % b for a, b in zip(op.array_shape, op.block_shape)):
             out.append(Diagnostic(
-                "RPC030", launch.subject,
+                "RPC030", subject,
                 f"{op.name}: block {op.block_shape} does not divide the "
                 f"padded array {op.array_shape}"))
             continue
         bounds = tuple(a // b for a, b in
                        zip(op.array_shape, op.block_shape))
-        for pt in _grid_points(launch.grid):
+        for pt in _grid_points(plan.grid):
             idx = tuple(op.index_map(*pt))
             if len(idx) != len(bounds):
                 out.append(Diagnostic(
-                    "RPC031", launch.subject,
+                    "RPC031", subject,
                     f"{op.name}: index map returns rank {len(idx)}, "
                     f"expected {len(bounds)}"))
                 break
             if any(i < 0 or i >= hi for i, hi in zip(idx, bounds)):
                 out.append(Diagnostic(
-                    "RPC031", launch.subject,
+                    "RPC031", subject,
                     f"{op.name}: index map sends grid point {pt} to block "
                     f"{idx}, valid range {tuple((0, hi - 1) for hi in bounds)}"
                 ))
                 break
-    if launch.vmem_bytes > budget:
+    vmem = launch_vmem_bytes(plan)
+    if vmem > budget:
         out.append(Diagnostic(
-            "RPC032", launch.subject,
-            f"per-step VMEM footprint {launch.vmem_bytes} B (blocks "
-            f"{launch.vmem_bytes - launch.scratch_bytes} + scratch "
-            f"{launch.scratch_bytes}) > budget {budget} B"))
+            "RPC032", subject,
+            f"VMEM footprint {vmem} B (double-buffered blocks + scratch) > "
+            f"limit {budget} B"))
     return out
 
 
 # ------------------------------------------------------------ conv2d_psum
-def conv_launch(cin: int, hp: int, wp: int, cout: int, kk: int, stride: int,
-                block_m: int, block_n: int, subject: str = "conv2d_psum",
-                elem_bytes: int = 4) -> LaunchSpec:
-    """Re-derive `conv2d_psum`'s launch for x (Cin, Hp, Wp), w (Cout, Cin,
-    K, K) — same clamp-to-extent and pad-to-multiple the kernel applies."""
-    ho = (hp - kk) // stride + 1
-    wo = (wp - kk) // stride + 1
-    bm = max(1, min(block_m, cin))
-    bn = max(1, min(block_n, cout))
-    cin_p = cin + (-cin) % bm
-    cout_p = cout + (-cout) % bn
-    n_co = cout_p // bn
-    n_ci = cin_p // bm
-    return LaunchSpec(
-        subject=subject,
-        grid=(n_co, n_ci),
-        operands=(
-            OperandSpec("x", (cin_p, hp, wp), (bm, hp, wp),
-                        lambda co, ci: (ci, 0, 0), elem_bytes),
-            OperandSpec("w", (cout_p, cin_p, kk, kk), (bn, bm, kk, kk),
-                        lambda co, ci: (co, ci, 0, 0), elem_bytes),
-            OperandSpec("out", (cout_p, ho, wo), (bn, ho, wo),
-                        lambda co, ci: (co, 0, 0), elem_bytes),
-        ),
-        scratch_bytes=bn * ho * wo * 4,       # fp32 accumulator
-    )
-
-
 def check_conv_launch(wl: ConvWorkload, schedule: Schedule,
                       subject: Optional[str] = None,
                       vmem_budget: Optional[int] = None) -> List[Diagnostic]:
@@ -189,90 +135,39 @@ def check_conv_launch(wl: ConvWorkload, schedule: Schedule,
             f"stride={wl.stride}) cannot produce ({wl.ho}x{wl.wo}); "
             f"shrink() the graph first"))
         return out
-    launch = conv_launch(wl.cin, wl.hi + 2 * pad, wl.wi + 2 * pad,
-                         wl.cout, wl.k, wl.stride,
-                         schedule.bm, schedule.bn, subject)
-    return out + check_launch(launch, vmem_budget)
+    from repro.kernels.conv2d_psum import conv_launch_plan
+    plan = conv_launch_plan(cin=wl.cin, hp=wl.hi + 2 * pad,
+                            wp=wl.wi + 2 * pad, cout=wl.cout, kk=wl.k,
+                            stride=wl.stride, block_m=schedule.bm,
+                            block_n=schedule.bn)
+    return out + check_launch(plan, vmem_budget, subject)
 
 
 # ------------------------------------------------------------ psum_matmul
-def matmul_launch(m: int, k: int, n: int, bm: int, bn: int, bk: int,
-                  controller: str, subject: str = "psum_matmul",
-                  in_bytes: int = 2) -> LaunchSpec:
-    """Re-derive `psum_matmul`'s launch: pad to block multiples, grid order
-    by controller, fp32 accumulator scratch only when active."""
-    mp = m + (-m) % bm
-    kp = k + (-k) % bk
-    np_ = n + (-n) % bn
-    gm, gn, gk = mp // bm, np_ // bn, kp // bk
-    if controller == "active":
-        grid = (gm, gn, gk)
-        x_map: IndexMap = lambda i, j, kk: (i, kk)      # noqa: E731
-        w_map: IndexMap = lambda i, j, kk: (kk, j)      # noqa: E731
-        o_map: IndexMap = lambda i, j, kk: (i, j)       # noqa: E731
-        out_bytes, scratch = in_bytes, bm * bn * 4
-    else:
-        grid = (gk, gm, gn)
-        x_map = lambda kk, i, j: (i, kk)                # noqa: E731
-        w_map = lambda kk, i, j: (kk, j)                # noqa: E731
-        o_map = lambda kk, i, j: (i, j)                 # noqa: E731
-        out_bytes, scratch = 4, 0                       # fp32 psum output
-    return LaunchSpec(
-        subject=subject,
-        grid=grid,
-        operands=(
-            OperandSpec("x", (mp, kp), (bm, bk), x_map, in_bytes),
-            OperandSpec("w", (kp, np_), (bk, bn), w_map, in_bytes),
-            OperandSpec("out", (mp, np_), (bm, bn), o_map, out_bytes),
-        ),
-        scratch_bytes=scratch,
-    )
-
-
 def check_matmul_launch(m: int, k: int, n: int, schedule: Schedule,
                         subject: str = "psum_matmul",
                         vmem_budget: Optional[int] = None
                         ) -> List[Diagnostic]:
+    """Pre-flight every launch `psum_matmul` issues under ``schedule`` (one,
+    or one per k-step for the passive controller)."""
     if schedule.kind != "matmul":
         return [Diagnostic(
             "RPC003", subject,
             f"kernel launch for a GEMM needs kind='matmul', got "
             f"{schedule.kind!r}")]
-    launch = matmul_launch(m, k, n, schedule.bm, schedule.bn, schedule.bk,
-                           schedule.controller.value, subject)
-    return check_launch(launch, vmem_budget)
+    from repro.kernels.psum_matmul import (matmul_launch_plan,
+                                           reduction_launches)
+    ctrl = schedule.controller.value
+    out: List[Diagnostic] = []
+    for step in range(reduction_launches(k, schedule.bk, ctrl)):
+        plan = matmul_launch_plan(m=m, k=k, n=n, bm=schedule.bm,
+                                  bn=schedule.bn, bk=schedule.bk,
+                                  controller=ctrl, k_step=step)
+        out += check_launch(plan, vmem_budget, subject)
+    return out
 
 
 # --------------------------------------------------------- flash_attention
-def flash_launch(bh: int, sq: int, skv: int, d: int, bq: int = 128,
-                 bk: int = 128, q_offset: int = 0,
-                 subject: str = "flash_attention",
-                 elem_bytes: int = 4) -> LaunchSpec:
-    """Re-derive `flash_attention`'s launch for q (BH, Sq, D), k/v (BH, Skv,
-    D) — same block clamping and sequence padding the kernel applies."""
-    bq = max(1, min(bq, sq))
-    bk = max(1, min(bk, skv))
-    sq_p = sq + (-sq) % bq
-    skv_p = skv + (-skv) % bk
-    gq = sq_p // bq
-    gk = skv_p // bk
-    return LaunchSpec(
-        subject=subject,
-        grid=(bh, gq, gk),
-        operands=(
-            OperandSpec("q", (bh, sq_p, d), (1, bq, d),
-                        lambda b, iq, ik: (b, iq, 0), elem_bytes),
-            OperandSpec("k", (bh, skv_p, d), (1, bk, d),
-                        lambda b, iq, ik: (b, ik, 0), elem_bytes),
-            OperandSpec("v", (bh, skv_p, d), (1, bk, d),
-                        lambda b, iq, ik: (b, ik, 0), elem_bytes),
-            OperandSpec("out", (bh, sq_p, d), (1, bq, d),
-                        lambda b, iq, ik: (b, iq, 0), elem_bytes),
-        ),
-        scratch_bytes=(bq * d + 2 * bq) * 4,   # fp32 acc + running (m, l)
-    )
-
-
 def check_flash_launch(bh: int, sq: int, skv: int, d: int, bq: int = 128,
                        bk: int = 128, causal: bool = True, q_offset: int = 0,
                        subject: str = "flash_attention",
@@ -298,8 +193,10 @@ def check_flash_launch(bh: int, sq: int, skv: int, d: int, bq: int = 128,
         out.append(Diagnostic(
             "RPC031", subject,
             f"negative q_offset={q_offset} puts query ids before key id 0"))
-    launch = flash_launch(bh, sq, skv, d, bq, bk, q_offset, subject)
-    return out + check_launch(launch, vmem_budget)
+    from repro.kernels.flash_attention import flash_launch_plan
+    plan = flash_launch_plan(bh=bh, sq=sq, skv=skv, d=d, bq=bq, bk=bk,
+                             causal=causal, q_offset=q_offset)
+    return out + check_launch(plan, vmem_budget, subject)
 
 
 def preflight_flash_launch(bh: int, sq: int, skv: int, d: int, bq: int = 128,

@@ -314,7 +314,7 @@ def default_rules() -> List[LintRule]:
 
 
 # ----------------------------------------------------------------- driver
-LINT_ROOTS = ("src", "benchmarks", "examples", "tools")
+LINT_ROOTS = ("src", "benchmarks", "examples", "tools", "chip_smoke.py")
 
 
 def find_repo_root(start: Optional[pathlib.Path] = None) -> pathlib.Path:
@@ -358,16 +358,14 @@ def lint_file(path: pathlib.Path, rel: str,
 def lint_repo(repo_root: Optional[pathlib.Path] = None,
               rules: Optional[Sequence[LintRule]] = None,
               roots: Iterable[str] = LINT_ROOTS) -> List[Diagnostic]:
-    """Lint every .py under the repo's source roots (tests are exempt: they
-    corrupt units on purpose)."""
+    """Lint every .py under the repo's source roots, and the root-level
+    scripts among them (tests are exempt: they corrupt units on purpose)."""
     root = repo_root or find_repo_root()
     rules = load_rules(root) if rules is None else list(rules)
     out: List[Diagnostic] = []
     for sub in roots:
         base = root / sub
-        if not base.is_dir():
-            continue
-        for py in sorted(base.rglob("*.py")):
+        for py in [base] if base.is_file() else sorted(base.rglob("*.py")):
             rel = py.relative_to(root).as_posix()
             out += lint_file(py, rel, rules)
     return out

@@ -12,7 +12,8 @@ The kernel accumulates in a VMEM-resident fp32 scratch (the active memory
 controller / fused-residency analogue), so this is the executable TPU-side
 counterpart of the planner's residency model. Graphs must be dense
 (groups == 1) with "same"-padded shapes — use ``NetworkGraph.shrink()`` on
-zoo nets; ``interpret=True`` (the default) runs on CPU.
+zoo nets. ``interpret`` is passed to every launch (`repro.kernels.launch.run`
+decides the default).
 """
 
 from __future__ import annotations
@@ -23,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.conv2d_psum import conv2d_psum
+from repro.kernels.launch import Interpret
 
 
 def init_network_params(graph, rng_seed: int = 0) -> dict[str, jax.Array]:
@@ -43,7 +45,7 @@ def init_network_params(graph, rng_seed: int = 0) -> dict[str, jax.Array]:
 
 def run_network_kernels(graph, schedules, params: dict[str, jax.Array],
                         inputs: dict[str, jax.Array] | None = None,
-                        rng_seed: int = 0, interpret: bool = True
+                        rng_seed: int = 0, interpret: Interpret = None
                         ) -> dict[str, jax.Array]:
     """Execute every conv of a planned graph with `conv2d_psum`.
 

@@ -6,8 +6,6 @@ materializing S = QK^T to HBM (which would be the passive schedule).
 Grid: (batch*heads, q_blocks, kv_blocks), kv innermost ('arbitrary'); causal
 masking skips fully-masked kv blocks' contribution via the mask itself (the
 index space is rectangular; masked blocks contribute exp(-inf)=0).
-
-TARGET: TPU. VALIDATED with interpret=True against ref.py.
 """
 
 from __future__ import annotations
@@ -105,7 +103,8 @@ def flash_launch_plan(*, bh: int, sq: int, skv: int, d: int, bq: int = 128,
                                              "q_offset"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, bq: int = 128, bk: int = 128,
-                    q_offset: int = 0, interpret: bool = True) -> jax.Array:
+                    q_offset: int = 0,
+                    interpret: launch.Interpret = None) -> jax.Array:
     """q: (BH, Sq, D), k/v: (BH, Skv, D). GQA is handled by the caller
     (reshape/broadcast of kv heads). q_offset shifts causal indices for
     decode (q positions start at q_offset).

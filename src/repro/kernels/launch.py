@@ -21,13 +21,13 @@ and are therefore callable from the checker without any arrays in hand.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional, Tuple
+from typing import Any, Callable, Optional, Tuple, Union
 
 import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
+from repro.plan.gemm_model import VMEM_LIMIT_BYTES
 
 IndexMap = Callable[..., Tuple[int, ...]]
 
@@ -100,10 +100,22 @@ class LaunchPlan:
                      if s != "parallel")
 
 
+# None: decided by `run` (compiled on a TPU backend, interpreted elsewhere).
+Interpret = Optional[Union[bool, pltpu.InterpretParams]]
+
+
 def run(plan: LaunchPlan, *operands: jax.Array,
-        interpret: bool = True) -> jax.Array:
+        interpret: Interpret = None) -> jax.Array:
     """Execute a single-output `LaunchPlan` — the one place in the repo that
-    invokes ``pl.pallas_call`` (RPL103 keeps it that way)."""
+    invokes ``pl.pallas_call`` (RPL103 keeps it that way).
+
+    ``interpret`` is where the library default is decided: ``None`` compiles
+    with Mosaic when JAX's default backend is a TPU and runs the Pallas
+    interpreter otherwise. ``False`` always compiles (and fails off-TPU),
+    ``True`` or a ``pltpu.InterpretParams`` always interprets. Every launch
+    requests the one VMEM limit the planner budgets against."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
     if len(operands) != len(plan.inputs):
         raise ValueError(f"{plan.name}: got {len(operands)} operands, plan "
                          f"has {len(plan.inputs)} inputs")
@@ -128,8 +140,9 @@ def run(plan: LaunchPlan, *operands: jax.Array,
             scratch_shapes=[
                 pltpu.VMEM(s.shape, s.dtype if s.dtype is not None
                            else jnp.float32) for s in plan.scratch],
-            compiler_params=CompilerParams(
-                dimension_semantics=plan.dimension_semantics),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=plan.dimension_semantics,
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
             interpret=interpret,
             **kwargs,
         )(*operands)

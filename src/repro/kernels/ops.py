@@ -4,8 +4,8 @@ These choose execution schedules via the unified planner (``repro.plan``) —
 the paper's partitioning policy applied to TPU tiles — and handle
 padding/layout so callers see plain array ops.
 
-``interpret`` defaults to True because this container is CPU-only; on real
-TPU hardware pass interpret=False (the kernels are written for Mosaic).
+``interpret`` is passed through to `repro.kernels.launch.run`, which decides
+the default (compiled on a TPU backend, the Pallas interpreter elsewhere).
 """
 
 from __future__ import annotations
@@ -21,11 +21,12 @@ from repro.plan import gemm_model as _gemm
 from repro.kernels import conv2d_psum as _conv
 from repro.kernels import flash_attention as _flash
 from repro.kernels import psum_matmul as _mm
+from repro.kernels.launch import Interpret
 
 
 def matmul(x: jax.Array, w: jax.Array, *, act: str = "none",
            controller: str = "active", vmem_budget: int | None = None,
-           interpret: bool = True) -> jax.Array:
+           interpret: Interpret = None) -> jax.Array:
     """Partial-sum-scheduled GEMM with planner-chosen blocks."""
     m, k = x.shape
     n = w.shape[1]
@@ -48,7 +49,7 @@ def _round_up(x: int, mult: int) -> int:
 
 def conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1, pad: int | None = None,
            p_macs: int = 2048, strategy: str = "paper_opt", act: str = "none",
-           interpret: bool = True) -> jax.Array:
+           interpret: Interpret = None) -> jax.Array:
     """Partitioned conv2d for one image. x: (Cin, H, W), w: (Cout, Cin, K, K).
     The (m, n) channel schedule comes from the paper's strategy at `p_macs`."""
     cin, h, w_sp = x.shape
@@ -69,7 +70,7 @@ def conv2d(x: jax.Array, w: jax.Array, *, stride: int = 1, pad: int | None = Non
 def gqa_flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True, q_offset: int = 0,
                         bq: int = 128, bk: int = 128,
-                        interpret: bool = True) -> jax.Array:
+                        interpret: Interpret = None) -> jax.Array:
     """q: (B, Hq, Sq, D); k/v: (B, Hkv, Skv, D) with Hq % Hkv == 0."""
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape

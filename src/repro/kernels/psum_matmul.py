@@ -12,18 +12,19 @@ different levels of the memory hierarchy:
   read-update-write locally), including the fused activation epilogue
   (the paper's ACT command).
 
-* ``passive`` — grid (gk, gm, gn), reduction outermost. Every k-step sweeps
-  all output blocks, so each C tile is written to and read back from HBM once
-  per reduction step (fp32), exactly the paper's "partial sums must be read
-  before being updated". This is the baseline whose traffic the paper (and our
-  ``repro.plan.gemm_model``) charges at ``(2*gk - 1) * M * N`` words.
+* ``passive`` — reduction outermost: one launch per k-step, each over the
+  (gm, gn) output grid. Step 0 writes the fp32 partial sums to HBM; every
+  later step reads them back as an input aliased to its output, adds its
+  contribution and writes them again — exactly the paper's "partial sums must
+  be read before being updated". This is the baseline whose traffic the paper
+  (and our ``repro.plan.gemm_model``) charges at ``(2*gk - 1) * M * N``
+  words, and every one of those words really crosses HBM: a Pallas TPU
+  kernel never fetches an output block, so a single launch with the
+  reduction outermost could not read its partial sums back.
 
 Schedules come from the unified planner: pass ``schedule=`` a
 ``repro.plan.Schedule`` (e.g. ``plan.plan(MatmulWorkload(...)).schedule``) —
 the integer-exact generalization of the paper's eq (7).
-
-TARGET: TPU (pl.pallas_call + BlockSpec, MXU-aligned blocks). VALIDATED on CPU
-via interpret=True against ``ref.py``.
 """
 
 from __future__ import annotations
@@ -63,23 +64,29 @@ def _active_kernel(x_ref, w_ref, o_ref, acc_ref, *, act: str, n_k: int):
         o_ref[...] = ACTIVATIONS[act](acc_ref[...]).astype(o_ref.dtype)
 
 
-def _passive_kernel(x_ref, w_ref, o_ref):
-    """Reduction-outermost: the output tile round-trips HBM per k-step."""
-    k = pl.program_id(0)
+def _passive_first_kernel(x_ref, w_ref, o_ref):
+    """Passive step 0: the first partial sums go straight out to HBM."""
+    o_ref[...] = jnp.dot(x_ref[...], w_ref[...],
+                         preferred_element_type=jnp.float32)
 
-    @pl.when(k == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
 
-    o_ref[...] += jnp.dot(x_ref[...], w_ref[...],
-                          preferred_element_type=jnp.float32)
+def _passive_kernel(x_ref, w_ref, p_ref, o_ref):
+    """Passive step k > 0: read the partial sums back, update, spill again."""
+    o_ref[...] = p_ref[...] + jnp.dot(x_ref[...], w_ref[...],
+                                      preferred_element_type=jnp.float32)
+
+
+def reduction_launches(k: int, bk: int, controller: str) -> int:
+    """How many launches one GEMM takes: one, or one per k-step (passive)."""
+    return -(-k // bk) if controller == "passive" else 1
 
 
 def matmul_launch_plan(*, m: int, k: int, n: int, bm: int, bn: int, bk: int,
                        controller: str = "active", act: str = "none",
-                       dtype=None) -> launch.LaunchPlan:
+                       dtype=None, k_step: int = 0) -> launch.LaunchPlan:
     """The launch `psum_matmul` executes for one controller, from plain
-    integers — shapes padded to block multiples exactly as the entry pads."""
+    integers — shapes padded to block multiples exactly as the entry pads.
+    A passive GEMM is `reduction_launches` launches; ``k_step`` picks one."""
     mp = m + (-m) % bm
     kp = k + (-k) % bk
     np_ = n + (-n) % bn
@@ -104,21 +111,28 @@ def matmul_launch_plan(*, m: int, k: int, n: int, bm: int, bn: int, bk: int,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         )
     if controller == "passive":
+        if not 0 <= k_step < gk:
+            raise ValueError(f"k_step {k_step} outside the {gk} k-steps")
+        inputs = (
+            launch.OperandPlan("x", (mp, kp), (bm, bk),
+                               lambda i, j: (i, k_step), elem_bytes=2),
+            launch.OperandPlan("w", (kp, np_), (bk, bn),
+                               lambda i, j: (k_step, j), elem_bytes=2),
+        )
+        if k_step:
+            inputs += (launch.OperandPlan("psums", (mp, np_), (bm, bn),
+                                          lambda i, j: (i, j)),)
         return launch.LaunchPlan(
-            name="psum_matmul/passive",
-            grid=(gk, gm, gn),
-            body=_passive_kernel,
-            inputs=(
-                launch.OperandPlan("x", (mp, kp), (bm, bk),
-                                   lambda kk, i, j: (i, kk), elem_bytes=2),
-                launch.OperandPlan("w", (kp, np_), (bk, bn),
-                                   lambda kk, i, j: (kk, j), elem_bytes=2),
-            ),
+            name=f"psum_matmul/passive/k{k_step}",
+            grid=(gm, gn),
+            body=_passive_kernel if k_step else _passive_first_kernel,
+            inputs=inputs,
             outputs=(
                 launch.OperandPlan("out", (mp, np_), (bm, bn),
-                                   lambda kk, i, j: (i, j), dtype=jnp.float32),
+                                   lambda i, j: (i, j), dtype=jnp.float32),
             ),
-            dimension_semantics=("arbitrary", "parallel", "parallel"),
+            dimension_semantics=("parallel", "parallel"),
+            input_output_aliases=((2, 0),) if k_step else (),
         )
     raise ValueError(controller)
 
@@ -136,7 +150,7 @@ def _pad_to(x: jax.Array, mult0: int, mult1: int) -> jax.Array:
                                              "out_dtype"))
 def psum_matmul(x: jax.Array, w: jax.Array, *, schedule=None, bm: int = 256,
                 bn: int = 256, bk: int = 256, act: str = "none",
-                controller: str = "active", interpret: bool = True,
+                controller: str = "active", interpret: launch.Interpret = None,
                 out_dtype=None) -> jax.Array:
     """C = act(x @ w) with explicit partial-sum schedule.
 
@@ -157,14 +171,17 @@ def psum_matmul(x: jax.Array, w: jax.Array, *, schedule=None, bm: int = 256,
         out_dtype = x.dtype
     xp = _pad_to(x, bm, bk)
     wp = _pad_to(w, bk, bn)
-    plan = matmul_launch_plan(m=m, k=k, n=n, bm=bm, bn=bn, bk=bk,
-                              controller=controller, act=act,
-                              dtype=out_dtype if controller == "active"
-                              else None)
+    kw = dict(m=m, k=k, n=n, bm=bm, bn=bn, bk=bk, controller=controller,
+              act=act)
     if controller == "active":
-        out = launch.run(plan, xp, wp, interpret=interpret)
+        out = launch.run(matmul_launch_plan(dtype=out_dtype, **kw), xp, wp,
+                         interpret=interpret)
     else:
-        psums = launch.run(plan, xp, wp, interpret=interpret)
+        psums = launch.run(matmul_launch_plan(**kw), xp, wp,
+                           interpret=interpret)
+        for step in range(1, reduction_launches(k, bk, controller)):
+            psums = launch.run(matmul_launch_plan(k_step=step, **kw), xp, wp,
+                               psums, interpret=interpret)
         # Passive engines apply the activation after reading the final psums
         # back — an extra HBM round-trip the active schedule fuses away.
         out = ACTIVATIONS[act](psums).astype(out_dtype)

@@ -193,7 +193,13 @@ def network_traffic(workloads, budget: int,
     wls = [dataclasses.replace(wl, groups=1)
            if paper_convention and wl.groups > 1 else wl for wl in workloads]
     plans = plan_many(wls, budget, strategy, controller, exact_iters=exact)
-    return sum(p.traffic.interconnect_words for p in plans)
+    # Plain left-to-right float addition, the tables' convention. Builtin
+    # sum() compensates exact Python floats but not numpy scalars, so its
+    # last bit would depend on which path produced each report.
+    total = 0.0
+    for p in plans:
+        total += float(p.traffic.interconnect_words)
+    return total
 
 
 def min_network_traffic(workloads) -> float:

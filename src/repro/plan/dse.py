@@ -73,8 +73,9 @@ class MacBudget:
 
 @dataclasses.dataclass(frozen=True)
 class VmemBudget:
-    """Block working set (double-buffered inputs + accumulator) fits the VMEM
-    byte budget; element widths come from the workload's dtypes."""
+    """The launch's VMEM footprint (`gemm_model.MatmulBlocks.vmem_bytes`:
+    double-buffered inputs and partial-sum tiles) fits the VMEM byte budget;
+    element widths come from the workload's dtypes."""
 
     double_buffer: bool = True
 
@@ -189,6 +190,7 @@ def _gemm_first_order_rule(max_block: int):
     def rule(wl: MatmulWorkload, budget: int):
         b = gemm_model.first_order_block(wl.m, wl.n, wl.k,
                                          in_bytes=wl.in_bytes,
+                                         acc_bytes=wl.acc_bytes,
                                          vmem_budget=budget,
                                          max_block=max_block)
         return b.bm, b.bn, b.bk

@@ -4,8 +4,11 @@ Single implementation of the block-shape search; ``core.partitioner`` is a
 thin shim over this module. The objective is the paper's first-order traffic
 model with the constraint swapped (eq 1's P MACs -> a VMEM byte budget):
 
-  paper:  K^2 * m * n                                      <= P MACs
-  here :  bytes(bm,bk) + bytes(bk,bn) + acc_bytes(bm,bn)   <= VMEM budget
+  paper:  K^2 * m * n                                           <= P MACs
+  here :  2 * (bytes(bm,bk) + bytes(bk,bn) + 2 * acc_bytes(bm,bn))  <= VMEM budget
+
+(the VMEM a `psum_matmul` launch allocates, double buffers counted — see
+`MatmulBlocks.vmem_bytes`; the budget is the limit every launch requests.)
 
 Traffic for C[M,N] = A[M,K] @ B[K,N] with grid (M/bm, N/bn, K/bk):
 
@@ -25,9 +28,14 @@ import numpy as np
 from repro.plan.schedule import Controller, Schedule, Strategy
 from repro.plan.workload import MatmulWorkload
 
-# TPU v5e-ish constants (see roofline/constants.py for the full set).
-VMEM_BYTES = 128 * 1024 * 1024  # 128 MiB VMEM per core (v5e: 128MB unified)
-DEFAULT_VMEM_BUDGET = 96 * 1024 * 1024  # leave headroom for double buffering
+# TPU v5e: 128 MiB of VMEM per TensorCore. Mosaic grants a kernel all of it
+# when asked through ``vmem_limit_bytes`` and only 16 MiB when not asked.
+VMEM_BYTES = 128 * 1024 * 1024
+# The one VMEM limit: every launch requests it (`repro.kernels.launch.run`),
+# the planner budgets against it and the launch preflight checks against it.
+# A quarter of VMEM stays free for Mosaic's internal scratch.
+VMEM_LIMIT_BYTES = VMEM_BYTES * 3 // 4
+DEFAULT_VMEM_BUDGET = VMEM_LIMIT_BYTES
 LANE = 128      # last-dim tile (MXU/VPU lane count)
 SUBLANE = 8     # second-to-last tile for fp32
 
@@ -40,9 +48,13 @@ class MatmulBlocks:
 
     def vmem_bytes(self, in_bytes: int = 2, acc_bytes: int = 4,
                    double_buffer: bool = True) -> int:
-        mult = 2 if double_buffer else 1   # double-buffered input blocks
-        return (mult * (self.bm * self.bk + self.bk * self.bn) * in_bytes
-                + self.bm * self.bn * acc_bytes)
+        """VMEM a `psum_matmul` launch allocates under either controller.
+
+        Pallas buffers every blocked operand (twice when double-buffered).
+        The passive launch streams an fp32 partial-sum tile in and out; that
+        bounds the active launch's accumulator plus its output tile."""
+        return int(vmem_bytes_grid(self.bm, self.bn, self.bk, in_bytes,
+                                   acc_bytes, double_buffer))
 
 
 def matmul_traffic(m: int, n: int, k: int, blocks, controller="active"
@@ -100,7 +112,7 @@ def vmem_bytes_grid(bm, bn, bk, in_bytes: int = 2, acc_bytes: int = 4,
     bn = np.asarray(bn, np.int64)
     bk = np.asarray(bk, np.int64)
     mult = 2 if double_buffer else 1
-    return (mult * (bm * bk + bk * bn) * in_bytes + bm * bn * acc_bytes)
+    return mult * ((bm * bk + bk * bn) * in_bytes + 2 * bm * bn * acc_bytes)
 
 
 def matmul_traffic_grid(m: int, n: int, k: int, bm, bn, bk,
@@ -189,15 +201,18 @@ def plan_matmul_blocks(m: int, n: int, k: int, *, in_bytes: int = 2,
 
 
 def first_order_block(m: int, n: int, k: int, *, in_bytes: int = 2,
+                      acc_bytes: int = 4,
                       vmem_budget: int = DEFAULT_VMEM_BUDGET,
                       max_block: int = 4096) -> MatmulBlocks:
     """Closed-form analogue of the paper's eq (7) for GEMM: with the input
-    terms dominating, minimize 1/bm + 1/bn s.t. bk*(bm+bn)*in_bytes <= V
-    -> bm = bn (the 'square block' rule), bk as large as the leftover allows."""
-    side = min(int(math.sqrt(vmem_budget / (4 * in_bytes))), max_block)
+    terms dominating, minimize 1/bm + 1/bn -> bm = bn (the 'square block'
+    rule). The partial-sum tiles take at most half of the VMEM model
+    (`MatmulBlocks.vmem_bytes`), and bk is as large as the leftover allows."""
+    side = min(int(math.sqrt(vmem_budget / (8 * acc_bytes))), max_block)
     bm = max(LANE, (min(side, m) // LANE) * LANE)
     bn = max(LANE, (min(side, n) // LANE) * LANE)
-    bk_budget = vmem_budget // (2 * in_bytes * (bm + bn))
+    leftover = vmem_budget // 2 - 2 * bm * bn * acc_bytes
+    bk_budget = leftover // (in_bytes * (bm + bn))
     bk = max(LANE, (min(bk_budget, k) // LANE) * LANE)
     return MatmulBlocks(bm, bn, bk)
 

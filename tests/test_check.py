@@ -263,17 +263,20 @@ def test_rpc022_peak_resident_mismatch_warns():
 
 
 # --------------------------------------------------- kernel launch checks
+def _one_operand_launch(grid, array_shape, block_shape):
+    from repro.kernels.launch import LaunchPlan, OperandPlan
+    return LaunchPlan(
+        name="t", grid=grid, body=lambda *refs: None, inputs=(),
+        outputs=(OperandPlan("x", array_shape, block_shape, lambda i: (i,)),))
+
+
 def test_rpc030_blockspec_indivisible():
-    launch = rc.LaunchSpec(
-        subject="t", grid=(2,),
-        operands=(rc.OperandSpec("x", (100,), (32,), lambda i: (i,)),))
+    launch = _one_operand_launch((2,), (100,), (32,))
     assert "RPC030" in _codes(rc.check_launch(launch))
 
 
 def test_rpc031_index_map_out_of_range():
-    launch = rc.LaunchSpec(
-        subject="t", grid=(4,),
-        operands=(rc.OperandSpec("x", (64,), (32,), lambda i: (i,)),))
+    launch = _one_operand_launch((4,), (64,), (32,))
     assert "RPC031" in _codes(rc.check_launch(launch))   # blocks 0..1, grid 0..3
 
 
@@ -281,14 +284,14 @@ def test_rpc032_kernel_vmem_exceeded():
     wl = ConvWorkload(name="t", cin=64, cout=64, k=3, wi=56, hi=56,
                       wo=56, ho=56)
     sched = Schedule(kind="conv", bm=64, bn=64)
-    assert rc.check_conv_launch(wl, sched) == []         # fits 128 MiB
+    assert rc.check_conv_launch(wl, sched) == []         # fits the limit
     got = rc.check_conv_launch(wl, sched, vmem_budget=1 << 16)
     assert "RPC032" in _codes(got)
 
 
 def test_kernel_launch_checks_match_real_kernels():
-    # the checker re-derives the kernels' geometry; anything it admits at
-    # defaults must actually execute
+    # the checker reads the kernels' own launch plans; anything it admits
+    # at defaults must actually execute
     import numpy as np
     from repro.kernels.conv2d_psum import conv2d_psum
     wl = ConvWorkload(name="t", cin=6, cout=10, k=3, wi=8, hi=8,

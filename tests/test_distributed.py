@@ -16,6 +16,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def run_sub(body: str, devices: int = 8, timeout: int = 420) -> str:
     env = dict(os.environ)
+    # CPU devices only: the parent may already hold the TPU, and a chip
+    # belongs to one process.
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     script = textwrap.dedent(body)

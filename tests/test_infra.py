@@ -199,3 +199,32 @@ def test_straggler_detector():
     assert det.observe(10, 1.0)       # 10x slower -> flagged
     assert det.report()["n_flagged"] == 1
     assert not det.observe(11, 0.1)   # ewma not polluted by the outlier
+
+
+# ------------------------------------------------------- compile cache place
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR wins and the code sets no other directory;
+    otherwise the cache goes to the caller's fixed directory."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from repro.launch.device import use_compile_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    if from_env:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "env"))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = use_compile_cache(tmp_path / "repo" / ".jax_cache")
+        if from_env:
+            assert got == str(tmp_path / "env")
+            assert jax.config.jax_compilation_cache_dir == saved[keys[0]]
+        else:
+            assert got == str(tmp_path / "repo" / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()

@@ -1,5 +1,6 @@
 """Per-kernel allclose sweeps (shapes x dtypes) against the jnp oracles,
-executed in interpret mode on CPU. Plus property tests on the schedules."""
+executed in interpret mode on CPU. Plus property tests on the schedules, and
+each kernel under the TPU-faithful interpreter."""
 
 import jax
 import jax.numpy as jnp
@@ -192,3 +193,72 @@ def test_property_flash_block_invariance(sq, d, bq, bk):
     want = ref.attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-4, atol=3e-4)
+
+
+# The TPU-faithful interpreter models what Mosaic does and the plain one
+# does not: grid semantics must be legal, and an output block is never
+# fetched from HBM, so a kernel that reads back an output block it did not
+# write in the same visit sees garbage (uninitialized memory is NaN).
+TPU_INTERPRETER_CASES = {
+    "matmul-active": dict(m=256, k=384, n=256, controller="active"),
+    # gm = gn = 2 output blocks, gk = 3 k-steps of HBM partial sums
+    "matmul-passive": dict(m=256, k=384, n=256, controller="passive"),
+    "conv-stride1": dict(cin=8, cout=16, k=3, h=12, stride=1, bm=4, bn=8),
+    "conv-stride2": dict(cin=6, cout=10, k=5, h=16, stride=2, bm=3, bn=5),
+    "flash": dict(bh=2, s=128, d=64, bq=64, bk=64),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TPU_INTERPRETER_CASES))
+def test_tpu_interpreter_matches_ref(case):
+    from jax.experimental.pallas import tpu as pltpu
+
+    from repro.kernels.conv2d_psum import conv2d_psum
+    from repro.kernels.flash_attention import flash_attention
+    tpu = pltpu.InterpretParams()
+    c = TPU_INTERPRETER_CASES[case]
+    rng = np.random.default_rng(len(case))
+    if case.startswith("matmul"):
+        x = _rand(rng, (c["m"], c["k"]), jnp.float32)
+        w = _rand(rng, (c["k"], c["n"]), jnp.float32)
+        got = psum_matmul(x, w, bm=128, bn=128, bk=128,
+                          controller=c["controller"], interpret=tpu)
+        want = ref.matmul_ref(x, w)
+    elif case.startswith("conv"):
+        side = c["h"] + 2 * (c["k"] // 2)
+        x = _rand(rng, (c["cin"], side, side), jnp.float32)
+        w = _rand(rng, (c["cout"], c["cin"], c["k"], c["k"]), jnp.float32)
+        got = conv2d_psum(x, w, block_m=c["bm"], block_n=c["bn"],
+                          stride=c["stride"], interpret=tpu)
+        want = ref.conv2d_ref(x, w, stride=c["stride"])
+    else:
+        q, k, v = (_rand(rng, (c["bh"], c["s"], c["d"]), jnp.float32)
+                   for _ in range(3))
+        got = flash_attention(q, k, v, bq=c["bq"], bk=c["bk"], interpret=tpu)
+        want = ref.attention_ref(q, k, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_launch_requests_the_planner_vmem_limit(monkeypatch):
+    """One VMEM constant: what every launch asks Mosaic for is the planner's
+    budget and the preflight's limit."""
+    from repro import plan
+    from repro.check.kernels import check_launch
+    from repro.kernels import launch
+    from repro.kernels.psum_matmul import matmul_launch_plan
+    from repro.plan.gemm_model import VMEM_LIMIT_BYTES
+    seen = []
+    real = launch.pl.pallas_call
+
+    def spy(*args, **kwargs):
+        seen.append(kwargs["compiler_params"].vmem_limit_bytes)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(launch.pl, "pallas_call", spy)
+    x = jnp.ones((24, 40), jnp.float32)       # a shape no other test traces
+    psum_matmul(x, x.T, bm=8, bn=128, bk=128)
+    assert seen == [VMEM_LIMIT_BYTES] == [plan.DEFAULT_VMEM_BUDGET]
+    big = matmul_launch_plan(m=4096, k=4096, n=4096, bm=4096, bn=4096,
+                             bk=4096)
+    assert "RPC032" in {d.code for d in check_launch(big)}

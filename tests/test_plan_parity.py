@@ -114,9 +114,12 @@ class _SeedBlocks:
     bk: int
 
     def vmem_bytes(self, in_bytes=2, acc_bytes=4, double_buffer=True):
+        # The seed counted one accumulator tile; the launches allocate every
+        # blocked operand twice and the passive one streams fp32 partial
+        # sums in and out, so the footprint the planner must respect is:
         mult = 2 if double_buffer else 1
-        return (mult * (self.bm * self.bk + self.bk * self.bn) * in_bytes
-                + self.bm * self.bn * acc_bytes)
+        return mult * ((self.bm * self.bk + self.bk * self.bn) * in_bytes
+                       + 2 * self.bm * self.bn * acc_bytes)
 
 
 def _seed_matmul_traffic(m, n, k, blocks, controller="active"):
