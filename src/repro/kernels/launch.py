@@ -127,22 +127,19 @@ def run(plan: LaunchPlan, *operands: jax.Array,
     if plan.input_output_aliases:
         kwargs["input_output_aliases"] = dict(plan.input_output_aliases)
     import jax.numpy as jnp
-    from repro.obs.trace import span
-    with span("kernel.launch", cat="kernel", plan=plan.name,
-              grid=plan.grid, interpret=interpret):
-        return pl.pallas_call(
-            plan.body,
-            grid=plan.grid,
-            in_specs=[pl.BlockSpec(op.block_shape, op.index_map)
-                      for op in plan.inputs],
-            out_specs=pl.BlockSpec(out.block_shape, out.index_map),
-            out_shape=jax.ShapeDtypeStruct(out.array_shape, out_dtype),
-            scratch_shapes=[
-                pltpu.VMEM(s.shape, s.dtype if s.dtype is not None
-                           else jnp.float32) for s in plan.scratch],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=plan.dimension_semantics,
-                vmem_limit_bytes=VMEM_LIMIT_BYTES),
-            interpret=interpret,
-            **kwargs,
-        )(*operands)
+    return pl.pallas_call(
+        plan.body,
+        grid=plan.grid,
+        in_specs=[pl.BlockSpec(op.block_shape, op.index_map)
+                  for op in plan.inputs],
+        out_specs=pl.BlockSpec(out.block_shape, out.index_map),
+        out_shape=jax.ShapeDtypeStruct(out.array_shape, out_dtype),
+        scratch_shapes=[
+            pltpu.VMEM(s.shape, s.dtype if s.dtype is not None
+                       else jnp.float32) for s in plan.scratch],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=plan.dimension_semantics,
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        **kwargs,
+    )(*operands)

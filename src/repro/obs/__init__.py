@@ -3,9 +3,10 @@
 Three parts, one package:
 
   * `repro.obs.trace` — contextvar-scoped runtime spans (`span`, `tracing`,
-    `Stopwatch`) with a no-op fast path when disabled; instrumented into the
-    planner, the simulator-scored beam, the planner service, and kernel
-    preflight/launch.
+    `Stopwatch`) with a no-op fast path when disabled, written into the JAX
+    profiler's trace while it records; instrumented into the planner, the
+    simulator-scored beam, the planner service, the kernel pre-flight, the
+    network runner and the garbage collector.
   * `repro.obs.metrics` — the process-wide metric `REGISTRY`
     (counters/gauges/histograms) that absorbs the planner's cache stats and
     the service's latency distribution; Prometheus text + JSON snapshot.

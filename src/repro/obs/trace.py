@@ -1,12 +1,26 @@
 """Runtime span tracing: stdlib-only, contextvar-scoped, no-op when off.
 
-The planner, the simulator-scored beam, the planner service, and the kernel
-pre-flight/launch paths are instrumented with `span` blocks. When no tracer
-is installed (the default), ``span(...)`` returns a shared no-op context
-manager — one module-global read plus an allocation-free ``with`` — so the
-instrumented hot paths pay effectively nothing (the ``obs`` benchmark section
-measures the ceiling and ``benchmarks/run.py check`` enforces it at <= 5% of
-the planserve smoke stream).
+The planner, the simulator-scored beam, the planner service, the kernel
+pre-flight and the network runner are instrumented with `span` blocks. When
+neither a tracer is installed nor the JAX profiler recording (the default),
+``span(...)`` returns a shared no-op context manager — one module-global read,
+one profiler check, plus an allocation-free ``with`` — so the instrumented hot
+paths pay effectively nothing (the ``obs`` benchmark section measures the
+ceiling and ``benchmarks/run.py check`` enforces it at <= 5% of the planserve
+smoke stream).
+
+While the JAX profiler records (``jax.profiler.trace`` / ``start_trace``),
+every ``span`` block also opens a ``jax.profiler.TraceAnnotation`` of the same
+name, its attributes as TraceMe metadata, so the span lands in the
+``.xplane.pb`` on the clock of the device's operations. JAX is never imported
+from here: until the program has imported ``jax.profiler`` no profiler can be
+recording, and the check costs a dictionary lookup; after, it is
+``TraceAnnotation.is_enabled()``.
+
+Garbage-collector pauses are spans too: a ``gc.callbacks`` hook, installed
+when this module is imported, opens `GC_SPAN` (attribute ``generation``) at a
+collection's start and closes it at its stop, through `span`, so it records
+only while a tracer or the profiler is on.
 
 When a `Tracer` is installed (`enable()` / the `tracing()` context manager),
 every ``span`` block records a `SpanRecord` carrying wall-clock start/
@@ -25,7 +39,9 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import itertools
+import sys
 import threading
 import time
 from dataclasses import dataclass
@@ -33,7 +49,9 @@ from types import TracebackType
 from typing import Any, Iterator, Optional
 
 __all__ = ["SpanRecord", "Tracer", "Stopwatch", "span", "enabled",
-           "enable", "disable", "get_tracer", "tracing"]
+           "enable", "disable", "get_tracer", "tracing", "GC_SPAN"]
+
+GC_SPAN = "obs.gc"
 
 
 @dataclass(frozen=True)
@@ -61,7 +79,9 @@ class Tracer:
     def __init__(self) -> None:
         self.spans: list[SpanRecord] = []
         self._ids = itertools.count(1)
-        self._lock = threading.Lock()
+        # Reentrant: a collection can start inside ``record`` and record its
+        # `GC_SPAN` from the same thread.
+        self._lock = threading.RLock()
 
     def __len__(self) -> int:
         return len(self.spans)
@@ -92,15 +112,29 @@ class Tracer:
 _CURRENT: contextvars.ContextVar[Optional[int]] = \
     contextvars.ContextVar("repro_obs_current_span", default=None)
 
-# The installed tracer. A plain module global read is the entire disabled-path
-# dispatch cost.
+# The installed tracer, read once per ``span()`` call.
 _TRACER: Optional[Tracer] = None
+
+# jax.profiler.TraceAnnotation, once the program has imported it.
+_ANNOTATION: Any = None
+
+
+def _find_annotation() -> Any:
+    """``jax.profiler.TraceAnnotation`` once the program has imported
+    ``jax.profiler``, else None: no profiler can record before then. Looked
+    up, never imported, so that a collection during JAX's own import never
+    re-enters it."""
+    global _ANNOTATION
+    _ANNOTATION = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation",
+                          None)
+    return _ANNOTATION
 
 
 class _NoopSpan:
     """Shared, allocation-free ``with`` target for the disabled path."""
 
     __slots__ = ()
+    recording = False
 
     def __enter__(self) -> "_NoopSpan":
         return self
@@ -118,12 +152,15 @@ _NOOP = _NoopSpan()
 
 
 class _Span:
-    """A live span: times itself and records on exit."""
+    """A live span: records itself in the tracer on exit (when one is
+    installed) and in the profiler's trace (when it records)."""
 
-    __slots__ = ("_tracer", "name", "cat", "_attrs", "_t0", "_id", "_token")
+    __slots__ = ("_tracer", "name", "cat", "_attrs", "_t0", "_id", "_token",
+                 "_annotation")
+    recording = True
 
-    def __init__(self, tracer: Tracer, name: str, cat: str,
-                 attrs: dict[str, Any]) -> None:
+    def __init__(self, tracer: Optional[Tracer], name: str, cat: str,
+                 attrs: dict[str, Any], profiled: bool) -> None:
         self._tracer = tracer
         self.name = name
         self.cat = cat
@@ -131,32 +168,42 @@ class _Span:
         self._t0 = 0.0
         self._id = 0
         self._token: Optional[contextvars.Token[Optional[int]]] = None
+        self._annotation: Any = _ANNOTATION(name, **attrs) if profiled \
+            else None
 
     def set(self, key: str, value: Any) -> None:
         """Attach an attribute to the running span."""
         self._attrs[key] = value
+        if self._annotation is not None:
+            self._annotation.set_metadata(**{key: value})
 
     def __enter__(self) -> "_Span":
-        self._id = self._tracer.next_id()
-        self._token = _CURRENT.set(self._id)
-        self._t0 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        if self._tracer is not None:
+            self._id = self._tracer.next_id()
+            self._token = _CURRENT.set(self._id)
+            self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type: Optional[type[BaseException]],
                  exc: Optional[BaseException],
                  tb: Optional[TracebackType]) -> None:
-        dur = time.perf_counter() - self._t0
-        token = self._token
-        parent: Optional[int] = None
-        if token is not None:
-            parent = token.old_value if token.old_value \
-                is not contextvars.Token.MISSING else None
-            _CURRENT.reset(token)
         if exc_type is not None:
-            self._attrs["error"] = exc_type.__name__
-        self._tracer.record(self.name, self._t0, dur, cat=self.cat,
-                            span_id=self._id, parent_id=parent,
-                            attrs=tuple(self._attrs.items()))
+            self.set("error", exc_type.__name__)
+        if self._tracer is not None:
+            dur = time.perf_counter() - self._t0
+            token = self._token
+            parent: Optional[int] = None
+            if token is not None:
+                parent = token.old_value if token.old_value \
+                    is not contextvars.Token.MISSING else None
+                _CURRENT.reset(token)
+            self._tracer.record(self.name, self._t0, dur, cat=self.cat,
+                                span_id=self._id, parent_id=parent,
+                                attrs=tuple(self._attrs.items()))
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         return None
 
 
@@ -166,13 +213,39 @@ def span(name: str, cat: str = "repro", **attrs: Any) -> "_Span | _NoopSpan":
         with obs.span("plan_graph", cat="plan", graph=name):
             ...
 
-    The disabled path is one global read plus the shared `_NoopSpan` —
-    safe to leave in hot control paths.
+    The span records into the installed `Tracer`, and into the JAX
+    profiler's trace while it records. The disabled path (neither) is one
+    global read, one profiler check and the shared `_NoopSpan` — safe to
+    leave in hot control paths. A loop that opens many spans can check
+    once: ``recording`` is False on the no-op, whose ``with`` costs less
+    than a ``span()`` call.
     """
     tr = _TRACER
-    if tr is None:
+    annotation = _ANNOTATION or _find_annotation()
+    profiled = annotation is not None and annotation.is_enabled()
+    if tr is None and not profiled:
         return _NOOP
-    return _Span(tr, name, cat, attrs)
+    return _Span(tr, name, cat, attrs, profiled)
+
+
+# The collection in progress, when a tracer or the profiler was on at its
+# start. Collections never overlap: the interpreter runs one at a time.
+_GC_OPEN: "_Span | _NoopSpan" = _NOOP
+
+
+def _on_gc(phase: str, info: dict[str, int]) -> None:
+    """``gc.callbacks`` hook: a `GC_SPAN` span from a collection's start to
+    its stop."""
+    global _GC_OPEN
+    if phase == "start":
+        _GC_OPEN = span(GC_SPAN, cat="obs", generation=info["generation"])
+        _GC_OPEN.__enter__()
+    else:
+        opened, _GC_OPEN = _GC_OPEN, _NOOP
+        opened.__exit__(None, None, None)
+
+
+gc.callbacks.append(_on_gc)
 
 
 def enabled() -> bool:
@@ -218,7 +291,7 @@ def tracing(tracer: Optional[Tracer] = None) -> Iterator[Tracer]:
 
 
 class Stopwatch:
-    """Measure one wall-clock interval (and span it, when named + tracing).
+    """Measure one wall-clock interval (and span it, when named and traced).
 
         with Stopwatch() as sw:
             work()

@@ -6,8 +6,11 @@ including a hypothesis property over random workloads x controllers that
 per-track trace cycles and counter words reproduce ``SimReport`` totals
 word-for-word."""
 
+import contextlib
+import gc
 import json
 import math
+import pathlib
 import threading
 
 import pytest
@@ -30,6 +33,12 @@ from repro.plan.workload import ConvWorkload
 CONTROLLERS = (Controller.PASSIVE, Controller.ACTIVE)
 
 
+def _own(tr):
+    """The spans a test opened: a collection inside it may add `GC_SPAN`
+    spans of its own."""
+    return [s for s in tr.spans if s.name != obs_trace.GC_SPAN]
+
+
 # ------------------------------------------------------------------ tracer
 def test_disabled_span_is_shared_noop():
     assert not obs.enabled()
@@ -47,8 +56,8 @@ def test_tracing_records_nested_spans_with_parents():
             with obs_trace.span("inner", cat="t") as sp:
                 sp.set("late", "v")
     assert not obs.enabled()          # restored on exit
-    assert len(tr) == 2
-    by_name = {s.name: s for s in tr.spans}
+    assert len(_own(tr)) == 2
+    by_name = {s.name: s for s in _own(tr)}
     outer, inner = by_name["outer"], by_name["inner"]
     assert outer.parent_id is None
     assert inner.parent_id == outer.span_id
@@ -65,7 +74,7 @@ def test_tracing_restores_previous_tracer():
             with obs_trace.span("in-scope"):
                 pass
         assert obs.get_tracer() is base
-        assert len(inner) == 1 and len(base) == 0
+        assert len(_own(inner)) == 1 and len(_own(base)) == 0
     finally:
         obs.disable()
 
@@ -75,7 +84,7 @@ def test_span_records_error_attr():
         with pytest.raises(RuntimeError):
             with obs_trace.span("boom"):
                 raise RuntimeError("x")
-    (s,) = tr.spans
+    (s,) = _own(tr)
     assert dict(s.attrs)["error"] == "RuntimeError"
 
 
@@ -114,9 +123,136 @@ def test_stopwatch_measures_and_spans_when_named():
         with obs.Stopwatch() as anon:
             pass
     assert anon.s >= 0.0
-    (s,) = tr.spans                   # only the named stopwatch spans
+    (s,) = _own(tr)                   # only the named stopwatch spans
     assert s.name == "timed.step" and s.cat == "c"
     assert named.s >= 0.0
+
+
+# ------------------------------------------------------- profiler bridge
+def _profiled(log_dir):
+    """A JAX profiler session around a block, host annotations only."""
+    jax = pytest.importorskip("jax")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    return jax.profiler.trace(str(log_dir), profiler_options=opts)
+
+
+def _host_events(log_dir, prefix):
+    """{name: [events]} of the host events whose names start with
+    ``prefix``, in the one ``.xplane.pb`` under ``log_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = pathlib.Path(log_dir).glob("plugins/profile/*/*.xplane.pb")
+    out = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name.startswith(prefix):
+                        out.setdefault(e.name, []).append(e)
+    return out
+
+
+@pytest.mark.parametrize("with_tracer", (False, True))
+def test_spans_reach_the_profiler_trace_with_their_metadata(tmp_path,
+                                                            with_tracer):
+    """While the profiler records, nested spans land on the host plane
+    under their own names, keyword and ``.set()`` attributes as event
+    stats, with or without a tracer installed."""
+    tracer = obs_trace.Tracer() if with_tracer else None
+    with _profiled(tmp_path):
+        with obs.tracing(tracer) if with_tracer else contextlib.nullcontext():
+            with obs_trace.span("bridge.outer", cat="t", graph="g"):
+                with obs_trace.span("bridge.inner", n=3) as sp:
+                    sp.set("diagnostics", 2)
+    ev = _host_events(tmp_path, "bridge.")
+    (outer,), (inner,) = ev["bridge.outer"], ev["bridge.inner"]
+    assert outer.start_ns <= inner.start_ns <= inner.end_ns <= outer.end_ns
+    assert dict(outer.stats) == {"graph": "g"}
+    assert dict(inner.stats) == {"n": 3, "diagnostics": 2}
+    if with_tracer:
+        by_name = {r.name: r for r in _own(tracer)}
+        assert dict(by_name["bridge.inner"].attrs) == {"n": 3,
+                                                       "diagnostics": 2}
+        assert by_name["bridge.inner"].parent_id == \
+            by_name["bridge.outer"].span_id
+
+
+def test_span_off_path_is_the_shared_noop_around_a_profiled_region(
+        tmp_path):
+    """With JAX imported, no profiler and no tracer, ``span()`` is still
+    the shared no-op; only the profiled region gets live spans."""
+    jax = pytest.importorskip("jax")
+    assert obs_trace.span("a", k=1) is obs_trace._NOOP
+    assert not obs_trace._NOOP.recording
+    with _profiled(tmp_path):
+        live = obs_trace.span("b", k=1)
+    assert live is not obs_trace._NOOP and live.recording
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert not obs.enabled()
+    assert obs_trace.span("c", k=1) is obs_trace._NOOP
+
+
+@pytest.mark.parametrize("where", ("tracer", "profiler"))
+def test_gc_pause_is_a_span(tmp_path, where):
+    """A full collection under a tracer or under the profiler is one
+    `GC_SPAN` span, attribute ``generation``."""
+    if where == "tracer":
+        with obs.tracing() as tr:
+            gc.collect()
+        pauses = [dict(s.attrs) for s in tr.spans
+                  if s.name == obs_trace.GC_SPAN]
+        assert {"generation": 2} in pauses
+        assert all(s.cat == "obs" for s in tr.spans
+                   if s.name == obs_trace.GC_SPAN)
+    else:
+        with _profiled(tmp_path):
+            gc.collect()
+        pauses = _host_events(tmp_path, obs_trace.GC_SPAN)[obs_trace.GC_SPAN]
+        assert {"generation": 2} in [dict(e.stats) for e in pauses]
+    assert obs_trace._GC_OPEN is obs_trace._NOOP      # closed at its stop
+
+
+def test_network_runner_spans_each_layer_boundary():
+    """One `run_network_kernels` call: a ``network.step`` holding the
+    pre-flight, one ``network.conv`` per conv and one ``network.glue`` per
+    eager op group (input, concat + pad per conv, add, pool)."""
+    pytest.importorskip("jax")
+    from repro.kernels.conv_network import (init_network_params,
+                                            run_network_kernels)
+    from repro.plan import netplan
+    from repro.plan.graph import NetworkGraph, Node, Tensor
+
+    def conv(name, cin, cout, k):
+        return ConvWorkload(name=name, cin=cin, cout=cout, k=k, wi=8, hi=8,
+                            wo=8, ho=8)
+
+    tensors = {n: Tensor(n, c, 8, 8) for n, c in
+               (("x", 4), ("a", 8), ("b", 8), ("s", 8), ("p", 8), ("y", 8))}
+    g = NetworkGraph("tiny", (
+        Node("in", "input", (), "x"),
+        Node("c1", "conv", ("x",), "a", conv("c1", 4, 8, 3)),
+        Node("c2", "conv", ("a",), "b", conv("c2", 8, 8, 3)),
+        Node("add", "add", ("a", "b"), "s"),
+        Node("pool", "pool", ("s",), "p"),
+        Node("c3", "conv", ("p", "x"), "y", conv("c3", 12, 8, 1)),
+    ), tensors)
+    netp = netplan.plan_graph(g, 512, "exact_opt", "active")
+    with obs.tracing() as tr:
+        run_network_kernels(g, netp, init_network_params(g))
+    spans = _own(tr)
+    (step,) = [s for s in spans if s.name == "network.step"]
+    assert step.parent_id is None and dict(step.attrs)["graph"] == "tiny"
+    children = [s for s in spans if s is not step]
+    assert all(s.parent_id == step.span_id for s in children)
+    assert sorted(s.name for s in children) == sorted(
+        ["kernel.preflight"] + ["network.conv"] * 3 + ["network.glue"] * 6)
+    assert [dict(s.attrs)["node"] for s in children
+            if s.name == "network.conv"] == ["c1", "c2", "c3"]
+    assert [(dict(s.attrs)["node"], dict(s.attrs)["op"]) for s in children
+            if s.name == "network.glue"] == [
+        ("in", "input"), ("c1", "concat_pad"), ("c2", "concat_pad"),
+        ("add", "add"), ("pool", "pool"), ("c3", "concat_pad")]
 
 
 # ----------------------------------------------------------------- metrics
@@ -254,7 +390,9 @@ def test_spans_to_trace_structure():
         with obs_trace.span("outer", cat="t"):
             with obs_trace.span("inner", cat="t"):
                 pass
-    events = obs_export.spans_to_trace(tr, process_name="unit")
+    own = obs_trace.Tracer()
+    own.spans.extend(_own(tr))
+    events = obs_export.spans_to_trace(own, process_name="unit")
     _assert_valid_trace_events(events)
     metas = [e for e in events if e["ph"] == "M"]
     assert any(e["name"] == "process_name"
