@@ -14,8 +14,9 @@ diagnostic *before* the first `pallas_call`.
 
 from __future__ import annotations
 
+import functools
 import itertools
-from typing import Any, List, Mapping, Optional, Sequence
+from typing import Any, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -112,35 +113,42 @@ def check_conv_launch(wl: ConvWorkload, schedule: Schedule,
                       subject: Optional[str] = None,
                       vmem_budget: Optional[int] = None) -> List[Diagnostic]:
     """Pre-flight one conv node as `run_network_kernels` would launch it:
-    channel-concatenated "same"-padded input, schedule blocks."""
+    channel-concatenated "same"-padded input, schedule blocks.
+
+    The proof is memoized per distinct launch (workload, schedule, subject,
+    budget), as the dataflow proof is per geometry; every call gets a fresh
+    list."""
     subject = subject or getattr(wl, "name", "conv2d_psum")
-    out: List[Diagnostic] = []
+    budget = VMEM_LIMIT_BYTES if vmem_budget is None else int(vmem_budget)
+    return list(_conv_launch_cached(wl, schedule, subject, budget))
+
+
+@functools.lru_cache(maxsize=512)
+def _conv_launch_cached(wl: ConvWorkload, schedule: Schedule, subject: str,
+                        budget: int) -> Tuple[Diagnostic, ...]:
     if schedule.kind != "conv":
-        out.append(Diagnostic(
+        return (Diagnostic(
             "RPC003", subject,
             f"kernel launch for a conv needs kind='conv', got "
-            f"{schedule.kind!r}"))
-        return out
+            f"{schedule.kind!r}"),)
     if wl.groups != 1:
-        out.append(Diagnostic(
+        return (Diagnostic(
             "RPC031", subject,
-            f"conv2d_psum executes dense convs only (groups={wl.groups})"))
-        return out
+            f"conv2d_psum executes dense convs only (groups={wl.groups})"),)
     pad = wl.k // 2
     if (wl.hi + 2 * pad - wl.k) // wl.stride + 1 != wl.ho or \
             (wl.wi + 2 * pad - wl.k) // wl.stride + 1 != wl.wo:
-        out.append(Diagnostic(
+        return (Diagnostic(
             "RPC031", subject,
             f"not 'same'-padded: ({wl.hi}x{wl.wi}, k={wl.k}, "
             f"stride={wl.stride}) cannot produce ({wl.ho}x{wl.wo}); "
-            f"shrink() the graph first"))
-        return out
+            f"shrink() the graph first"),)
     from repro.kernels.conv2d_psum import conv_launch_plan
     plan = conv_launch_plan(cin=wl.cin, hp=wl.hi + 2 * pad,
                             wp=wl.wi + 2 * pad, cout=wl.cout, kk=wl.k,
                             stride=wl.stride, block_m=schedule.bm,
                             block_n=schedule.bn)
-    return out + check_launch(plan, vmem_budget, subject)
+    return tuple(check_launch(plan, budget, subject))
 
 
 # ------------------------------------------------------------ psum_matmul
@@ -258,15 +266,27 @@ def preflight_network_kernels(graph: NetworkGraph, schedules: Any,
     """The gate `run_network_kernels` calls before any pallas_call: raises
     `CheckError` listing every RPC03x/RPC04x error, compiles nothing.
 
-    With ``dataflow`` (the default) every node's launch is also traced by
-    `repro.check.dataflow` — race/coverage/accumulation proofs plus the
-    eq (2)/(3) word-count equivalence — cached per launch geometry, so the
-    added cost across a whole zoo is a handful of traces.
+    Each conv's launch-geometry proof is memoized per distinct launch
+    (`check_conv_launch`), so a repeated call proves only launches it has not
+    seen; the schedule and weight lookups (RPC033, RPC031) run on every call,
+    and a failing launch raises on every call. With ``dataflow`` (the
+    default) every node's launch is also traced by `repro.check.dataflow` —
+    race/coverage/accumulation proofs plus the eq (2)/(3) word-count
+    equivalence — cached per launch geometry, so the added cost across a
+    whole zoo is a handful of traces.
+
+    The ``kernel.preflight`` span carries ``geometry_cached`` and
+    ``geometry_proved``: the conv launches served from the memo and those
+    proven in this call.
     """
     from repro.obs.trace import span
     with span("kernel.preflight", cat="kernel", graph=graph.name,
               dataflow=dataflow) as sp:
+        before = _conv_launch_cached.cache_info()
         found = check_network_kernels(graph, schedules, params, vmem_budget)
+        after = _conv_launch_cached.cache_info()
+        sp.set("geometry_cached", after.hits - before.hits)
+        sp.set("geometry_proved", after.misses - before.misses)
         if dataflow and not errors(found):
             from repro.check.dataflow import check_network_dataflow
             found += check_network_dataflow(graph, schedules)

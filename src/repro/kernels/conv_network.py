@@ -58,6 +58,8 @@ def run_network_kernels(graph, schedules, params: dict[str, jax.Array],
     schedules/weights, weight-shape mismatches, non-dense or non-"same"
     shapes, BlockSpec geometry and VMEM footprint all raise a
     `repro.check.CheckError` *before* the first `pallas_call` compiles.
+    The geometry proof is memoized per distinct launch, as the dataflow
+    proof is, so after the first call each image pays only the lookups.
 
     Spans (`repro.obs.span`, in the profiler's trace while it records):
     ``network.step`` around the whole call, holding ``kernel.preflight``,

@@ -340,6 +340,156 @@ def test_preflight_gate_rejects_before_compile(monkeypatch):
     assert rc.check_network_kernels(g, netp, params) == []
 
 
+# ------------------------------------------- memoized launch-geometry proof
+@pytest.fixture
+def cold_launch_memo():
+    """An empty conv launch memo, emptied again after the test so that
+    launches proven under a patched plan builder never leak out."""
+    from repro.check import kernels as rk
+    rk._conv_launch_cached.cache_clear()
+    yield rk
+    rk._conv_launch_cached.cache_clear()
+
+
+def _break_x(monkeypatch, how):
+    """Patch `conv_launch_plan` so its input operand fails ``how``."""
+    from repro.kernels import conv2d_psum as kc
+    real = kc.conv_launch_plan
+
+    def broken(**kw):
+        plan = real(**kw)
+        x = plan.inputs[0]
+        if how == "RPC030":      # last block dim no longer divides the array
+            x = dataclasses.replace(
+                x, block_shape=x.block_shape[:-1] + (x.block_shape[-1] - 1,))
+        else:                    # RPC031: the last ci step maps past the end
+            x = dataclasses.replace(
+                x, index_map=lambda co, ci: (0, ci + 1, 0, 0))
+        return dataclasses.replace(plan, inputs=(x,) + plan.inputs[1:])
+
+    monkeypatch.setattr(kc, "conv_launch_plan", broken)
+
+
+@pytest.mark.parametrize("case", ["clean", "RPC030", "RPC031", "RPC032"])
+def test_conv_launch_memo_matches_uncached_check(case, cold_launch_memo,
+                                                  monkeypatch):
+    from repro.kernels import conv2d_psum as kc
+    rk = cold_launch_memo
+    wl = ConvWorkload(name=f"memo_{case}", cin=64, cout=64, k=3, wi=56,
+                      hi=56, wo=56, ho=56)
+    sched = Schedule(kind="conv", bm=8, bn=16)
+    budget = (1 << 16) if case == "RPC032" else None
+    if case in ("RPC030", "RPC031"):
+        _break_x(monkeypatch, case)
+    direct = rc.check_launch(
+        kc.conv_launch_plan(cin=wl.cin, hp=wl.hi + 2, wp=wl.wi + 2,
+                            cout=wl.cout, kk=wl.k, stride=wl.stride,
+                            block_m=sched.bm, block_n=sched.bn),
+        budget, wl.name)
+    assert (case == "clean") == (direct == [])
+    if case != "clean":
+        assert case in _codes(direct)
+    first = rc.check_conv_launch(wl, sched, vmem_budget=budget)
+    assert first == direct
+    first.append("caller's own")            # a fresh list: the memo is safe
+    hits = rk._conv_launch_cached.cache_info().hits
+    assert rc.check_conv_launch(wl, sched, vmem_budget=budget) == direct
+    assert rk._conv_launch_cached.cache_info().hits == hits + 1
+
+
+def test_conv_launch_memo_keys_on_budget(cold_launch_memo):
+    wl = ConvWorkload(name="t", cin=64, cout=64, k=3, wi=56, hi=56,
+                      wo=56, ho=56)
+    sched = Schedule(kind="conv", bm=64, bn=64)
+    from repro.plan.gemm_model import VMEM_LIMIT_BYTES
+    assert rc.check_conv_launch(wl, sched) == []
+    # None and the default limit share one entry
+    rc.check_conv_launch(wl, sched, vmem_budget=VMEM_LIMIT_BYTES)
+    assert cold_launch_memo._conv_launch_cached.cache_info().currsize == 1
+    # a smaller budget is a new launch: proven afresh, never served clean
+    assert "RPC032" in _codes(
+        rc.check_conv_launch(wl, sched, vmem_budget=1 << 16))
+    assert rc.check_conv_launch(wl, sched) == []
+
+
+@dataclasses.dataclass(frozen=True)
+class _Shaped:
+    """Weights stand-in: the pre-flight reads only ``shape``."""
+    shape: tuple
+
+
+def _resnet18_preflight_inputs():
+    netp = plan.plan_graph("resnet18", controller="active")
+    g = netp.graph
+    convs = [n for n in g.workload_nodes
+             if isinstance(n.workload, ConvWorkload)]
+    params = {n.name: _Shaped((n.workload.cout, n.workload.cin,
+                               n.workload.k, n.workload.k)) for n in convs}
+    return netp, g, convs, params
+
+
+def test_second_preflight_does_not_reprove_geometry(cold_launch_memo,
+                                                    monkeypatch):
+    rk = cold_launch_memo
+    netp, g, _, params = _resnet18_preflight_inputs()
+    calls = []
+    real = rk.check_launch
+
+    def spy(*a, **k):
+        calls.append(a)
+        return real(*a, **k)
+
+    monkeypatch.setattr(rk, "check_launch", spy)
+    rk.preflight_network_kernels(g, netp, params)
+    assert calls                                  # cold: proven here
+    calls.clear()
+    rk.preflight_network_kernels(g, netp, params)
+    assert calls == []
+
+
+def test_memoized_preflight_still_raises_on_every_call(cold_launch_memo):
+    rk = cold_launch_memo
+    netp, g, convs, params = _resnet18_preflight_inputs()
+    rk.preflight_network_kernels(g, netp, params, dataflow=False)   # clean
+    first = convs[0].name
+
+    # the lookups run on every call: a wrong weight shape, a missing schedule
+    wrong = dict(params, **{first: _Shaped((1, 1, 1, 1))})
+    with pytest.raises(rc.CheckError) as exc:
+        rk.preflight_network_kernels(g, netp, wrong, dataflow=False)
+    assert "RPC031" in _codes(exc.value.diagnostics)
+    missing = {k: v for k, v in netp.schedules.items() if k != first}
+    with pytest.raises(rc.CheckError) as exc:
+        rk.preflight_network_kernels(g, missing, params, dataflow=False)
+    assert "RPC033" in _codes(exc.value.diagnostics)
+
+    # a failing launch raises on its proving call and again from the memo
+    for _ in range(2):
+        with pytest.raises(rc.CheckError) as exc:
+            rk.preflight_network_kernels(g, netp, params, vmem_budget=1 << 10,
+                                         dataflow=False)
+        assert "RPC032" in _codes(exc.value.diagnostics)
+    # and a schedules mapping mutated after a clean call is proven afresh
+    scheds = dict(netp.schedules)
+    rk.preflight_network_kernels(g, scheds, params, dataflow=False)
+    scheds[first] = Schedule(kind="matmul", bm=128, bn=128, bk=128)
+    with pytest.raises(rc.CheckError) as exc:
+        rk.preflight_network_kernels(g, scheds, params, dataflow=False)
+    assert "RPC003" in _codes(exc.value.diagnostics)
+
+
+def test_preflight_span_counts_memo_hits(cold_launch_memo):
+    from repro import obs
+    rk = cold_launch_memo
+    netp, g, convs, params = _resnet18_preflight_inputs()
+    with obs.tracing() as tr:
+        rk.preflight_network_kernels(g, netp, params, dataflow=False)
+        rk.preflight_network_kernels(g, netp, params, dataflow=False)
+    got = [dict(s.attrs) for s in tr.spans if s.name == "kernel.preflight"]
+    assert [(a["geometry_proved"], a["geometry_cached"]) for a in got] == [
+        (len(convs), 0), (0, len(convs))]
+
+
 # ----------------------------------------------------------- checked=True
 def test_checked_plan_raises_on_infeasible_budget():
     wl = _conv_wl(k=7)     # K^2 = 49 > budget: even bm=bn=1 violates eq (1)
