@@ -255,6 +255,13 @@ def analyze_launch(plan, subject: Optional[str] = None
     `flash_dataflow`."""
     subject = subject or plan.name
     out: List[Diagnostic] = []
+    prefetch = getattr(plan, "prefetch", ())
+    if prefetch:
+        return [Diagnostic(
+            "RPC046", subject,
+            f"index maps and body read the scalar-prefetch operands "
+            f"{[p.name for p in prefetch]}: which block each grid step "
+            f"touches depends on device data; dataflow proofs skipped")], None
     try:
         trace = trace_launch(plan)
     except UntraceableKernel as exc:
@@ -609,6 +616,12 @@ def matmul_dataflow(wl: MatmulWorkload, schedule: Schedule,
     from repro.plan.gemm_model import matmul_traffic
     ctrl = schedule.controller.value
     subject = subject or f"dataflow/{wl.name}/{ctrl}"
+    if wl.groups > 1:
+        return DataflowReport(subject, (Diagnostic(
+            "RPC046", subject,
+            "grouped GEMM: its row tiles map to groups through "
+            "scalar-prefetch operands computed on the device; no word-count "
+            "proof"),), {})
     geo = check_matmul_launch(wl.m, wl.k, wl.n, schedule, subject)
     if errors(geo):
         return DataflowReport(subject, tuple(geo), {})

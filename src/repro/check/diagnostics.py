@@ -128,6 +128,11 @@ _register("RPC033", "unplanned-node", Severity.ERROR,
           "a workload node has no schedule (or no kernel params) assigned",
           "plan the whole graph (plan_graph) or pass a complete "
           "{node: Schedule} mapping")
+_register("RPC034", "prefetch-index-unchecked", Severity.WARNING,
+          "the launch's index maps read scalar-prefetch operands, so its "
+          "block indices depend on device data and were not checked",
+          "keep the data-dependent map inside the bounds by construction "
+          "(clamp it) and test the kernel against a reference")
 
 # --- kernel-body dataflow analysis (repro.check.dataflow) -------------------
 _register("RPC040", "write-write-race", Severity.ERROR,

@@ -56,9 +56,20 @@ def launch_vmem_bytes(plan: Any) -> int:
 def check_launch(plan: Any, vmem_budget: Optional[int] = None,
                  subject: Optional[str] = None) -> List[Diagnostic]:
     """RPC030 (divisibility), RPC031 (index map range / rank), RPC032 (VMEM)
-    for a `LaunchPlan` (or anything with its name/grid/operands/scratch)."""
+    for a `LaunchPlan` (or anything with its name/grid/operands/scratch).
+
+    A launch with scalar-prefetch operands gets RPC030 and RPC032, which do
+    not depend on device data, and an RPC034 warning in place of the index
+    maps' range and rank, which do."""
     out: List[Diagnostic] = []
     subject = subject or plan.name
+    prefetch = getattr(plan, "prefetch", ())
+    if prefetch:
+        out.append(Diagnostic(
+            "RPC034", subject,
+            f"index maps read the scalar-prefetch operands "
+            f"{[p.name for p in prefetch]}; block indices and their range "
+            f"were not checked"))
     budget = VMEM_LIMIT_BYTES if vmem_budget is None else int(vmem_budget)
     if any(g < 1 for g in plan.grid):
         out.append(Diagnostic(
@@ -84,7 +95,7 @@ def check_launch(plan: Any, vmem_budget: Optional[int] = None,
             continue
         bounds = tuple(a // b for a, b in
                        zip(op.array_shape, op.block_shape))
-        for pt in _grid_points(plan.grid):
+        for pt in (() if prefetch else _grid_points(plan.grid)):
             idx = tuple(op.index_map(*pt))
             if len(idx) != len(bounds):
                 out.append(Diagnostic(

@@ -63,7 +63,8 @@ def check_workload(wl: Workload, subject: Optional[str] = None
                 f"cout={wl.cout}"))
     elif isinstance(wl, MatmulWorkload):
         dims = dict(m=wl.m, n=wl.n, k=wl.k, in_bytes=wl.in_bytes,
-                    out_bytes=wl.out_bytes, acc_bytes=wl.acc_bytes)
+                    out_bytes=wl.out_bytes, acc_bytes=wl.acc_bytes,
+                    groups=wl.groups)
         bad = {k: v for k, v in dims.items() if v < 1}
         if bad:
             out.append(Diagnostic("RPC008", subject,
@@ -183,7 +184,7 @@ def check_traffic(wl: Workload, schedule: Schedule, report: TrafficReport,
         expect = gemm_model.traffic_model_bytes(
             wl.m, wl.n, wl.k, schedule, schedule.controller,
             in_bytes=wl.in_bytes, out_bytes=wl.out_bytes,
-            acc_bytes=wl.acc_bytes)
+            acc_bytes=wl.acc_bytes, groups=wl.groups)
         if report.bytes != expect:
             out.append(Diagnostic(
                 "RPC010", subject,

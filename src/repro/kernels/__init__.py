@@ -2,7 +2,10 @@
 
   psum_matmul.py      blocked GEMM: active (VMEM-resident accumulator,
                       reduction-innermost grid) vs passive (HBM psum spill,
-                      reduction-outermost) schedules + fused activation
+                      reduction-outermost) schedules + fused activation;
+                      psum_grouped_matmul, its grouped (per-expert) form
+  moe_ffn.py          a DeepSeek-V2-style FFN stack: router, sort, grouped
+                      and shared-expert GEMMs, combine; spans per layer
   conv2d_psum.py      the paper's channel-partitioned conv loop nest on MXU
   conv_network.py     whole-network runner: chains conv2d_psum over a
                       planned repro.plan.graph.NetworkGraph (branches, adds)

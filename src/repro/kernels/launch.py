@@ -13,9 +13,16 @@ kernel *body* itself (with its static keywords bound) — so that
     abstractly and `repro.check.dataflow` proves race-freedom, coverage and
     word-count equivalence from the same object that executes).
 
-Builders (`conv_launch_plan` / `matmul_launch_plan` / `flash_launch_plan`)
-take plain integers, apply exactly the clamping/padding their kernel applies,
-and are therefore callable from the checker without any arrays in hand.
+Builders (`conv_launch_plan` / `matmul_launch_plan` / `flash_launch_plan` /
+`grouped_matmul_launch_plan`) take plain integers, apply exactly the
+clamping/padding their kernel applies, and are therefore callable from the
+checker without any arrays in hand.
+
+A plan may carry scalar-prefetch operands (``prefetch``): small int32 arrays
+that Pallas copies to SMEM before the grid starts, which the index maps and
+the body read. Their block indices then depend on device data, which the
+static checker cannot see (`repro.check` reports such a launch as unchecked
+where it would otherwise prove).
 """
 
 from __future__ import annotations
@@ -68,12 +75,23 @@ class ScratchPlan:
 
 
 @dataclasses.dataclass(frozen=True)
+class PrefetchPlan:
+    """One scalar-prefetch operand: an int32 array held in SMEM. Index maps
+    receive these refs after the grid indices; the body receives them before
+    the inputs."""
+
+    name: str
+    shape: Tuple[int, ...]
+
+
+@dataclasses.dataclass(frozen=True)
 class LaunchPlan:
     """A complete, executable-and-checkable Pallas launch description.
 
     ``body`` is the kernel function with every static keyword already bound
     (``functools.partial``); its positional refs arrive in the pallas order:
-    inputs, then outputs, then scratch.
+    scalar-prefetch operands, inputs, then outputs, then scratch.
+    ``input_output_aliases`` index ``inputs`` (not counting ``prefetch``).
     """
 
     name: str
@@ -84,6 +102,7 @@ class LaunchPlan:
     scratch: Tuple[ScratchPlan, ...] = ()
     dimension_semantics: Tuple[str, ...] = ()
     input_output_aliases: Tuple[Tuple[int, int], ...] = ()
+    prefetch: Tuple[PrefetchPlan, ...] = ()
 
     @property
     def operands(self) -> Tuple[OperandPlan, ...]:
@@ -109,37 +128,45 @@ def run(plan: LaunchPlan, *operands: jax.Array,
     """Execute a single-output `LaunchPlan` — the one place in the repo that
     invokes ``pl.pallas_call`` (RPL103 keeps it that way).
 
-    ``interpret`` is where the library default is decided: ``None`` compiles
-    with Mosaic when JAX's default backend is a TPU and runs the Pallas
-    interpreter otherwise. ``False`` always compiles (and fails off-TPU),
-    ``True`` or a ``pltpu.InterpretParams`` always interprets. Every launch
-    requests the one VMEM limit the planner budgets against."""
+    ``operands`` are the plan's scalar-prefetch arrays (if any), then its
+    inputs. ``interpret`` is where the library default is decided: ``None``
+    compiles with Mosaic when JAX's default backend is a TPU and runs the
+    Pallas interpreter otherwise. ``False`` always compiles (and fails
+    off-TPU), ``True`` or a ``pltpu.InterpretParams`` always interprets.
+    Every launch requests the one VMEM limit the planner budgets against."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    if len(operands) != len(plan.inputs):
+    n_pre = len(plan.prefetch)
+    if len(operands) != n_pre + len(plan.inputs):
         raise ValueError(f"{plan.name}: got {len(operands)} operands, plan "
-                         f"has {len(plan.inputs)} inputs")
+                         f"has {n_pre} prefetch + {len(plan.inputs)} inputs")
     if len(plan.outputs) != 1:
         raise NotImplementedError("run() supports single-output plans")
     out = plan.outputs[0]
-    out_dtype = out.dtype if out.dtype is not None else operands[0].dtype
+    out_dtype = out.dtype if out.dtype is not None else operands[n_pre].dtype
     kwargs: dict[str, Any] = {}
     if plan.input_output_aliases:
-        kwargs["input_output_aliases"] = dict(plan.input_output_aliases)
+        kwargs["input_output_aliases"] = {
+            i + n_pre: o for i, o in plan.input_output_aliases}
     import jax.numpy as jnp
-    return pl.pallas_call(
-        plan.body,
+    specs: dict[str, Any] = dict(
         grid=plan.grid,
         in_specs=[pl.BlockSpec(op.block_shape, op.index_map)
                   for op in plan.inputs],
         out_specs=pl.BlockSpec(out.block_shape, out.index_map),
-        out_shape=jax.ShapeDtypeStruct(out.array_shape, out_dtype),
         scratch_shapes=[
             pltpu.VMEM(s.shape, s.dtype if s.dtype is not None
-                       else jnp.float32) for s in plan.scratch],
+                       else jnp.float32) for s in plan.scratch])
+    if n_pre:
+        specs = {"grid_spec": pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_pre, **specs)}
+    return pl.pallas_call(
+        plan.body,
+        out_shape=jax.ShapeDtypeStruct(out.array_shape, out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=plan.dimension_semantics,
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        **specs,
         **kwargs,
     )(*operands)

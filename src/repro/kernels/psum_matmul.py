@@ -25,6 +25,14 @@ different levels of the memory hierarchy:
 Schedules come from the unified planner: pass ``schedule=`` a
 ``repro.plan.Schedule`` (e.g. ``plan.plan(MatmulWorkload(...)).schedule``) —
 the integer-exact generalization of the paper's eq (7).
+
+`psum_grouped_matmul` is the grouped form (a mixture of experts' FFN): rows
+sorted by group, one K x N weight per group, each row multiplied by its own
+group's weight. The same two controllers keep each group's partial sums in
+VMEM or spill them to HBM. Its row tiles map to groups through metadata
+computed on the device (`grouped_tiles`) and handed to the launch as
+scalar-prefetch operands, over a static worst-case grid, so no group size
+ever returns to the host.
 """
 
 from __future__ import annotations
@@ -135,6 +143,224 @@ def matmul_launch_plan(*, m: int, k: int, n: int, bm: int, bn: int, bk: int,
             input_output_aliases=((2, 0),) if k_step else (),
         )
     raise ValueError(controller)
+
+
+# ----------------------------------------------------------- grouped GEMM
+def grouped_row_tiles(rows: int, bm: int, groups: int) -> int:
+    """Row tiles a grouped launch's grid holds: every row tile, plus one
+    partial tile for each boundary between two groups inside a tile — the
+    static worst case over all group sizes that sum to ``rows``."""
+    return -(-rows // bm) + groups - 1
+
+
+def grouped_tiles(group_sizes: jax.Array, *, bm: int, tiles: int
+                  ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+    """The tile -> (group, row block) map of a grouped launch, on the device.
+
+    Group g owns rows ``[bounds[g], bounds[g + 1])``; it is visited once per
+    row block it touches, in row order, empty groups not at all. Returns
+    (``tile_group``, ``tile_block``, ``bounds``, ``live``): tile t computes
+    group ``tile_group[t]`` on row block ``tile_block[t]``; tiles from
+    ``live[0]`` on repeat the last live tile's indices, so their blocks are
+    never fetched again, and do nothing."""
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    first = starts // bm
+    count = jnp.where(sizes > 0, (ends - 1) // bm - first + 1, 0)
+    tile_end = jnp.cumsum(count)
+    live = tile_end[-1]
+    t = jnp.minimum(jnp.arange(tiles, dtype=jnp.int32),
+                    jnp.maximum(live - 1, 0))
+    group = jnp.minimum(jnp.sum(tile_end[None, :] <= t[:, None], axis=1,
+                                dtype=jnp.int32), sizes.shape[0] - 1)
+    block = first[group] + t - (tile_end[group] - count[group])
+    bounds = jnp.concatenate([jnp.zeros(1, jnp.int32), ends])
+    return group, block, bounds, live[None]
+
+
+def _group_rows(tile_group, tile_block, bounds, t, shape, bm: int
+                ) -> jax.Array:
+    """The rows of tile ``t``'s block that belong to its group."""
+    g = tile_group[t]
+    row = tile_block[t] * bm + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    return (row >= bounds[g]) & (row < bounds[g + 1])
+
+
+def _grouped_active_kernel(tile_group, tile_block, bounds, live, x_ref,
+                           w_ref, o_ref, acc_ref, *, act: str, n_k: int,
+                           bm: int):
+    """One group's rows of one row block: the f32 partial sums stay in VMEM
+    across k; the epilogue stores only the group's rows, so a block that two
+    groups share collects both while it stays resident."""
+    t, k = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(t < live[0])
+    def _tile():
+        @pl.when(k == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        acc_ref[...] += jnp.dot(x_ref[...], w_ref[0],
+                                preferred_element_type=jnp.float32)
+
+        @pl.when(k == n_k - 1)
+        def _epilogue():
+            mine = _group_rows(tile_group, tile_block, bounds, t,
+                               acc_ref.shape, bm)
+            o_ref[...] = jnp.where(
+                mine, ACTIVATIONS[act](acc_ref[...]),
+                o_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+
+
+def _grouped_passive_first_kernel(tile_group, tile_block, bounds, live,
+                                  x_ref, w_ref, o_ref, *, bm: int):
+    """Passive step 0: each group's first partial sums go out to HBM."""
+    t = pl.program_id(1)
+
+    @pl.when(t < live[0])
+    def _tile():
+        mine = _group_rows(tile_group, tile_block, bounds, t, o_ref.shape, bm)
+        o_ref[...] = jnp.where(
+            mine, jnp.dot(x_ref[...], w_ref[0],
+                          preferred_element_type=jnp.float32), o_ref[...])
+
+
+def _grouped_passive_kernel(tile_group, tile_block, bounds, live, x_ref,
+                            w_ref, p_ref, o_ref, *, bm: int):
+    """Passive step k > 0: read each group's partial sums back, update,
+    spill again."""
+    t = pl.program_id(1)
+
+    @pl.when(t < live[0])
+    def _tile():
+        mine = _group_rows(tile_group, tile_block, bounds, t, o_ref.shape, bm)
+        o_ref[...] = jnp.where(
+            mine, p_ref[...] + jnp.dot(x_ref[...], w_ref[0],
+                                       preferred_element_type=jnp.float32),
+            o_ref[...])
+
+
+def grouped_matmul_launch_plan(*, rows: int, k: int, n: int, groups: int,
+                               bm: int, bn: int, bk: int,
+                               controller: str = "active", act: str = "none",
+                               dtype=None, k_step: int = 0
+                               ) -> launch.LaunchPlan:
+    """The launch `psum_grouped_matmul` executes, from plain integers.
+
+    Grid (N blocks, row tiles, K blocks): the row tiles of one N block run
+    consecutively, so a row block that two groups share keeps its output
+    block resident between their visits. ``bn`` and ``bk`` must divide
+    ``n`` and ``k``: the weights are never padded. Rows are padded to a
+    multiple of ``bm``, as the entry pads them. A passive GEMM is
+    `reduction_launches` launches; ``k_step`` picks one."""
+    if n % bn or k % bk:
+        raise ValueError(f"grouped GEMM blocks ({bn}, {bk}) must divide "
+                         f"(n, k) = ({n}, {k})")
+    rp = rows + (-rows) % bm
+    gn, gk = n // bn, k // bk
+    tiles = grouped_row_tiles(rows, bm, groups)
+    prefetch = (launch.PrefetchPlan("tile_group", (tiles,)),
+                launch.PrefetchPlan("tile_block", (tiles,)),
+                launch.PrefetchPlan("bounds", (groups + 1,)),
+                launch.PrefetchPlan("live", (1,)))
+    if controller == "active":
+        return launch.LaunchPlan(
+            name="psum_grouped_matmul/active",
+            grid=(gn, tiles, gk),
+            body=functools.partial(_grouped_active_kernel, act=act, n_k=gk,
+                                   bm=bm),
+            inputs=(
+                launch.OperandPlan(
+                    "x", (rp, k), (bm, bk),
+                    lambda j, t, kk, tg, tb, *_: (tb[t], kk), elem_bytes=2),
+                launch.OperandPlan(
+                    "w", (groups, k, n), (1, bk, bn),
+                    lambda j, t, kk, tg, tb, *_: (tg[t], kk, j),
+                    elem_bytes=2),
+            ),
+            outputs=(
+                launch.OperandPlan(
+                    "out", (rp, n), (bm, bn),
+                    lambda j, t, kk, tg, tb, *_: (tb[t], j), dtype=dtype,
+                    elem_bytes=2),
+            ),
+            scratch=(launch.ScratchPlan("acc", (bm, bn), jnp.float32),),
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            prefetch=prefetch,
+        )
+    if controller == "passive":
+        if not 0 <= k_step < gk:
+            raise ValueError(f"k_step {k_step} outside the {gk} k-steps")
+        inputs = (
+            launch.OperandPlan(
+                "x", (rp, k), (bm, bk),
+                lambda j, t, tg, tb, *_: (tb[t], k_step), elem_bytes=2),
+            launch.OperandPlan(
+                "w", (groups, k, n), (1, bk, bn),
+                lambda j, t, tg, tb, *_: (tg[t], k_step, j), elem_bytes=2),
+        )
+        if k_step:
+            inputs += (launch.OperandPlan(
+                "psums", (rp, n), (bm, bn),
+                lambda j, t, tg, tb, *_: (tb[t], j)),)
+        return launch.LaunchPlan(
+            name=f"psum_grouped_matmul/passive/k{k_step}",
+            grid=(gn, tiles),
+            body=functools.partial(
+                _grouped_passive_kernel if k_step
+                else _grouped_passive_first_kernel, bm=bm),
+            inputs=inputs,
+            outputs=(
+                launch.OperandPlan(
+                    "out", (rp, n), (bm, bn),
+                    lambda j, t, tg, tb, *_: (tb[t], j), dtype=jnp.float32),
+            ),
+            dimension_semantics=("parallel", "arbitrary"),
+            input_output_aliases=((2, 0),) if k_step else (),
+            prefetch=prefetch,
+        )
+    raise ValueError(controller)
+
+
+@functools.partial(jax.jit, static_argnames=("schedule", "act", "interpret"))
+def psum_grouped_matmul(x_sorted: jax.Array, w: jax.Array,
+                        group_sizes: jax.Array, *, schedule, act: str = "none",
+                        interpret: launch.Interpret = None) -> jax.Array:
+    """Per-group ``act(x_g @ w[g])`` with an explicit partial-sum schedule.
+
+    x_sorted: (R, K), rows sorted by group; w: (G, K, N); group_sizes: (G,)
+    int32 on the device, summing to R (rows past their sum come back
+    unspecified). The schedule's blocks come from ``plan.plan(
+    MatmulWorkload(m=R, k=K, n=N, groups=G))``; its controller keeps each
+    group's f32 partial sums in VMEM (active) or spills them to HBM once per
+    k-step (passive). Returns (R, N) in ``x_sorted``'s dtype."""
+    if schedule.kind != "matmul":
+        raise ValueError(f"psum_grouped_matmul needs a matmul schedule, got "
+                         f"{schedule}")
+    bm, bn, bk = schedule.bm, schedule.bn, schedule.bk
+    controller = schedule.controller.value
+    rows, k = x_sorted.shape
+    groups, k2, n = w.shape
+    assert k == k2 and group_sizes.shape == (groups,), (
+        x_sorted.shape, w.shape, group_sizes.shape)
+    meta = grouped_tiles(group_sizes, bm=bm,
+                         tiles=grouped_row_tiles(rows, bm, groups))
+    xp = _pad_to(x_sorted, bm, 1)
+    kw = dict(rows=rows, k=k, n=n, groups=groups, bm=bm, bn=bn, bk=bk,
+              controller=controller, act=act)
+    if controller == "active":
+        out = launch.run(
+            grouped_matmul_launch_plan(dtype=x_sorted.dtype, **kw), *meta, xp,
+            w, interpret=interpret)
+    else:
+        psums = launch.run(grouped_matmul_launch_plan(**kw), *meta, xp, w,
+                           interpret=interpret)
+        for step in range(1, reduction_launches(k, bk, controller)):
+            psums = launch.run(grouped_matmul_launch_plan(k_step=step, **kw),
+                               *meta, xp, w, psums, interpret=interpret)
+        out = ACTIVATIONS[act](psums).astype(x_sorted.dtype)
+    return out[:rows]
 
 
 def _pad_to(x: jax.Array, mult0: int, mult1: int) -> jax.Array:

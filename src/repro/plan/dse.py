@@ -42,6 +42,7 @@ from repro.plan.workload import ConvWorkload, MatmulWorkload, Workload
 
 __all__ = [
     "Constraint", "MacBudget", "VmemBudget", "LaneAligned", "GroupDivisible",
+    "WholeGroupWeights",
     "StrategySpec", "SearchResult", "search", "plan_with_strategy",
     "strategy_spec", "register_strategy", "unregister_strategy",
     "sweep", "pareto", "certify_space", "register_objective", "get_objective",
@@ -99,6 +100,19 @@ class LaneAligned:
         return ((cands.bm % self.sublane_tile == 0)
                 & (cands.bn % self.lane == 0)
                 & (cands.bk % self.lane == 0))
+
+
+@dataclasses.dataclass(frozen=True)
+class WholeGroupWeights:
+    """Grouped GEMMs (``groups`` > 1): bn and bk divide N and K, so that the
+    kernel reads each group's weight in place and never pads it. A plain
+    GEMM passes."""
+
+    def __call__(self, wl: MatmulWorkload, cands: Candidates,
+                 budget: int) -> np.ndarray:
+        if wl.groups == 1:
+            return np.ones(len(cands), dtype=bool)
+        return (wl.n % cands.bn == 0) & (wl.k % cands.bk == 0)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -232,7 +246,8 @@ def _builtin_spec(name: str, kind: str, max_block: int) -> StrategySpec:
     if kind == "matmul":
         if name in _GEMM_EXACT:
             return StrategySpec(space=AlignedBlockSpace(max_block),
-                                constraints=(VmemBudget(),))
+                                constraints=(VmemBudget(),
+                                             WholeGroupWeights()))
         if name in _GEMM_CLOSED:
             return StrategySpec(space=ClosedFormSpace(
                 kind="matmul", rule=_gemm_first_order_rule(max_block)))

@@ -16,6 +16,15 @@ Traffic for C[M,N] = A[M,K] @ B[K,N] with grid (M/bm, N/bn, K/bk):
   B reads:  ceil(M/bm) * K * N
   C,active: M * N                        (accumulator VMEM-resident across k)
   C,passive: (2*ceil(K/bk) - 1) * M * N  (spill + read-back per k step)
+
+A grouped GEMM (``groups`` = G > 1: M rows sorted by group, one K x N weight
+per group) runs at most ceil(M/bm) + G - 1 row tiles: each boundary between
+two groups inside a row tile costs one more, partial, tile. Each row tile
+reads its group's weights, and each partial tile re-reads up to bm rows of
+A; C is written once (a shared row block stays resident between its groups):
+
+  A reads:  ceil(N/bn) * (M + (G - 1) * bm) * K
+  B reads:  (ceil(M/bm) + G - 1) * K * N
 """
 
 from __future__ import annotations
@@ -57,18 +66,19 @@ class MatmulBlocks:
                                    acc_bytes, double_buffer))
 
 
-def matmul_traffic(m: int, n: int, k: int, blocks, controller="active"
-                   ) -> dict[str, float]:
-    """HBM traffic in *elements* for the blocked GEMM.
+def matmul_traffic(m: int, n: int, k: int, blocks, controller="active",
+                   groups: int = 1) -> dict[str, float]:
+    """HBM traffic in *elements* for the blocked GEMM (``groups`` > 1: the
+    grouped GEMM's worst case over group sizes, see the module docstring).
 
     `blocks` is anything with bm/bn/bk (MatmulBlocks or a matmul Schedule);
     `controller` coerces from the legacy strings.
     """
     controller = Controller.coerce(controller)
-    gi = math.ceil(m / blocks.bm)
+    gi = math.ceil(m / blocks.bm) + groups - 1
     gj = math.ceil(n / blocks.bn)
     gk = math.ceil(k / blocks.bk)
-    a_reads = gj * m * k
+    a_reads = gj * m * k + gj * (groups - 1) * blocks.bm * k
     b_reads = gi * k * n
     if controller is Controller.ACTIVE:
         c_traffic = m * n
@@ -116,7 +126,8 @@ def vmem_bytes_grid(bm, bn, bk, in_bytes: int = 2, acc_bytes: int = 4,
 
 
 def matmul_traffic_grid(m: int, n: int, k: int, bm, bn, bk,
-                        controller="active") -> dict[str, np.ndarray]:
+                        controller="active", groups: int = 1
+                        ) -> dict[str, np.ndarray]:
     """Vectorized `matmul_traffic` over candidate block arrays; the ``total``
     entry is bit-identical to the scalar evaluator element-for-element
     (exact int64 arithmetic, one final float conversion)."""
@@ -124,10 +135,10 @@ def matmul_traffic_grid(m: int, n: int, k: int, bm, bn, bk,
     bm = np.asarray(bm, np.int64)
     bn = np.asarray(bn, np.int64)
     bk = np.asarray(bk, np.int64)
-    gi = -(-m // bm)
+    gi = -(-m // bm) + (groups - 1)
     gj = -(-n // bn)
     gk = -(-k // bk)
-    a_reads = gj * (m * k)
+    a_reads = gj * (m * k) + gj * ((groups - 1) * k) * bm
     b_reads = gi * (k * n)
     if controller is Controller.ACTIVE:
         c_traffic = np.full_like(a_reads, m * n)
@@ -141,13 +152,14 @@ def matmul_traffic_grid(m: int, n: int, k: int, bm, bn, bk,
 
 def traffic_model_bytes_grid(m: int, n: int, k: int, bm, bn, bk, controller,
                              in_bytes: int = 2, out_bytes: int = 2,
-                             acc_bytes: int = 4) -> np.ndarray:
+                             acc_bytes: int = 4, groups: int = 1
+                             ) -> np.ndarray:
     """Vectorized `traffic_model_bytes` over candidate block arrays — the one
     dtype-weighted byte model the `repro.plan.objectives` cost functions
     share. Passive spills move fp32 accumulators; the active final write is
     the output dtype."""
     controller = Controller.coerce(controller)
-    t = matmul_traffic_grid(m, n, k, bm, bn, bk, controller)
+    t = matmul_traffic_grid(m, n, k, bm, bn, bk, controller, groups)
     io = (t["a_reads"] + t["b_reads"]) * in_bytes
     if controller is Controller.ACTIVE:
         return io + float(m * n * out_bytes)
@@ -227,12 +239,12 @@ def conv_blocks_from_partition(m_part: int, n_part: int) -> tuple[int, int]:
 
 def traffic_model_bytes(m: int, n: int, k: int, blocks, controller,
                         in_bytes: int = 2, out_bytes: int = 2,
-                        acc_bytes: int = 4) -> float:
+                        acc_bytes: int = 4, groups: int = 1) -> float:
     """Traffic in bytes, distinguishing in/out/accumulator element widths.
     Passive spills move fp32 accumulators; the active final write is the
     output dtype — an additional saving the paper's word-count model hides."""
     controller = Controller.coerce(controller)
-    t = matmul_traffic(m, n, k, blocks, controller)
+    t = matmul_traffic(m, n, k, blocks, controller, groups)
     io = (t["a_reads"] + t["b_reads"]) * in_bytes
     if controller is Controller.ACTIVE:
         c = m * n * out_bytes

@@ -389,7 +389,7 @@ def _node_bus_report(wl, schedule: Schedule, spilled_in_words: int,
         word_bytes = wl.word_bytes
     elif isinstance(wl, MatmulWorkload):
         t = gemm_model.matmul_traffic(wl.m, wl.n, wl.k, schedule,
-                                      schedule.controller)
+                                      schedule.controller, wl.groups)
         gj = math.ceil(wl.n / schedule.bn)
         gk = math.ceil(wl.k / schedule.bk)
         in_bus = float(spilled_in_words * gj + t["b_reads"])

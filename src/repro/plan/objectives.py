@@ -90,7 +90,7 @@ def interconnect_words(wl: Workload, cands: Candidates,
     if isinstance(wl, MatmulWorkload):
         return gemm_model.matmul_traffic_grid(
             wl.m, wl.n, wl.k, cands.bm, cands.bn, cands.bk,
-            controller)["total"]
+            controller, wl.groups)["total"]
     raise _kind_error("interconnect_words", wl)
 
 
@@ -148,7 +148,7 @@ def energy_bytes(wl: Workload, cands: Candidates,
         ic_bytes = gemm_model.traffic_model_bytes_grid(
             wl.m, wl.n, wl.k, cands.bm, cands.bn, cands.bk, controller,
             in_bytes=wl.in_bytes, out_bytes=wl.out_bytes,
-            acc_bytes=wl.acc_bytes)
+            acc_bytes=wl.acc_bytes, groups=wl.groups)
         reads, writes = _matmul_sram(wl, cands)
         sram_bytes = (reads + writes) * wl.acc_bytes
     else:
@@ -173,7 +173,7 @@ def roofline_latency(wl: Workload, cands: Candidates,
         nbytes = gemm_model.traffic_model_bytes_grid(
             wl.m, wl.n, wl.k, cands.bm, cands.bn, cands.bk, controller,
             in_bytes=wl.in_bytes, out_bytes=wl.out_bytes,
-            acc_bytes=wl.acc_bytes)
+            acc_bytes=wl.acc_bytes, groups=wl.groups)
     else:
         raise _kind_error("roofline_latency", wl)
     return np.maximum(flops / PEAK_FLOPS_BF16, nbytes / HBM_BW)

@@ -74,10 +74,12 @@ def conv_traffic(wl: ConvWorkload, schedule: Schedule,
 
 def matmul_traffic_report(wl: MatmulWorkload, schedule: Schedule) -> TrafficReport:
     """Report for a blocked GEMM under the schedule's controller."""
-    t = gemm_model.matmul_traffic(wl.m, wl.n, wl.k, schedule, schedule.controller)
+    t = gemm_model.matmul_traffic(wl.m, wl.n, wl.k, schedule,
+                                  schedule.controller, wl.groups)
     nbytes = gemm_model.traffic_model_bytes(
         wl.m, wl.n, wl.k, schedule, schedule.controller,
-        in_bytes=wl.in_bytes, out_bytes=wl.out_bytes, acc_bytes=wl.acc_bytes)
+        in_bytes=wl.in_bytes, out_bytes=wl.out_bytes, acc_bytes=wl.acc_bytes,
+        groups=wl.groups)
     gk = math.ceil(wl.k / schedule.bk)
     acc = wl.m * wl.n
     return TrafficReport(

@@ -69,7 +69,11 @@ class ConvWorkload:
 
 @dataclasses.dataclass(frozen=True)
 class MatmulWorkload:
-    """One GEMM C[M,N] = A[M,K] @ B[K,N] with element widths."""
+    """One GEMM C[M,N] = A[M,K] @ B[K,N] with element widths.
+
+    ``groups`` > 1 is a grouped GEMM (a mixture of experts' FFN): ``m`` rows
+    over all groups, sorted by group, and each group with its own K x N
+    weight (`repro.kernels.psum_matmul.psum_grouped_matmul`)."""
 
     m: int
     n: int
@@ -78,6 +82,7 @@ class MatmulWorkload:
     in_bytes: int = 2     # bf16 operands
     out_bytes: int = 2
     acc_bytes: int = 4    # fp32 partial sums
+    groups: int = 1
 
     @property
     def flops(self) -> int:

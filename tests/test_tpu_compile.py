@@ -100,3 +100,38 @@ def test_flash_attention_compiles(one_chip):
     shape = ((12, 2048, 128), jnp.bfloat16)
     _compile(flash_attention, shape, shape, shape, one_chip=one_chip,
              causal=True)
+
+
+EXPERT_UP = plan.MatmulWorkload(m=12288, n=1408, k=2048, groups=64)
+# DeepSeek-V2-Lite: 2048 tokens x top-6 routed rows over 64 experts of 1408
+
+
+@pytest.mark.parametrize("controller", ["active", "passive"])
+def test_psum_grouped_matmul_compiles(one_chip, controller):
+    from repro.kernels.psum_matmul import psum_grouped_matmul
+    sched = plan.plan(EXPERT_UP, strategy="exhaustive_vmem",
+                      controller=controller).schedule
+    wl = EXPERT_UP
+    _compile(psum_grouped_matmul, ((wl.m, wl.k), jnp.bfloat16),
+             ((wl.groups, wl.k, wl.n), jnp.bfloat16),
+             ((wl.groups,), jnp.int32), one_chip=one_chip, schedule=sched)
+
+
+def test_moe_layer_compiles(one_chip):
+    """The whole MoE layer (router, sort, gather, grouped and shared GEMMs,
+    combine) at DeepSeek-V2-Lite's widths, every launch compiled."""
+    from repro.kernels import moe_ffn
+    d, f, e, shared = 2048, 1408, 64, 2816
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params = {"router": sds((d, e), jnp.float32),
+              "gate": sds((e, d, f)), "up": sds((e, d, f)),
+              "down": sds((e, f, d)),
+              "shared": {"gate": sds((d, shared)), "up": sds((d, shared)),
+                         "down": sds((shared, d))}}
+    layer = moe_ffn.ffn_layer(
+        "moe", params, moe_ffn.moe_schedules(2048, d, f, e, 6, shared),
+        eps=1e-6, top_k=6, interpret=False)
+    text = layer.call.lower(sds((2048, d)), params).compile().as_text()
+    assert text.count("tpu_custom_call") == 6
