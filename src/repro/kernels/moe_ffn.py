@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.kernels.launch import Interpret
 from repro.kernels.psum_matmul import (grouped_row_tiles, grouped_tiles,
+                                       grouped_weight_fetches,
                                        psum_grouped_matmul, psum_matmul)
 from repro.obs.trace import span
 
@@ -224,24 +225,32 @@ def _expert_rows(x: jax.Array, router: jax.Array, *, top_k: int,
 def routing_stats(x: jax.Array, layers: List[FfnLayer], *, eps: float
                   ) -> List[Dict[str, Any]]:
     """For each MoE layer of the stack, on input ``x``: the most and the
-    mean rows an expert receives, the experts that receive none, and the
-    rows the grouped kernel computes (its live row tiles, of the
-    ``expert_up`` schedule's ``bm`` rows each) over the rows routed. Runs
-    the stack once and waits for it: a set-up measurement, never timed."""
+    mean rows an expert receives, the experts that receive none, and, for
+    the grouped gate (or up) launch at the ``expert_up`` schedule, the rows
+    it computes (its live row tiles of ``bm`` rows each) over the rows
+    routed and the weight blocks it fetches over those of one read of every
+    expert that receives rows. Runs the stack once and waits for it: a
+    set-up measurement, never timed."""
     out: List[Dict[str, Any]] = []
     for i, layer in enumerate(layers):
         if layer.kind == "moe":
             sizes = _expert_rows(x, layer.params["router"], top_k=layer.top_k,
                                  eps=eps)
-            bm = layer.schedules["expert_up"].bm
+            up = layer.schedules["expert_up"]
             rows = x.shape[0] * layer.top_k
-            live = grouped_tiles(sizes, bm=bm, tiles=grouped_row_tiles(
-                rows, bm, layer.experts))[3]
-            sizes, live = jax.device_get((sizes, live))
+            k, n = layer.params["gate"].shape[1:]
+            gk, gn = k // up.bk, n // up.bn
+            tile_group, _, _, live = grouped_tiles(
+                sizes, bm=up.bm,
+                tiles=grouped_row_tiles(rows, up.bm, layer.experts))
+            fetches = grouped_weight_fetches(tile_group, live, gn=gn, gk=gk)
+            sizes, live, fetches = jax.device_get((sizes, live, fetches))
             out.append({"layer": i, "max_rows": int(sizes.max()),
                         "mean_rows": rows / layer.experts,
                         "empty_experts": int(np.sum(sizes == 0)),
-                        "tile_rows_per_row": int(live[0]) * bm / rows})
+                        "tile_rows_per_row": int(live[0]) * up.bm / rows,
+                        "weight_reads_per_expert": int(fetches) / (
+                            int(np.sum(sizes > 0)) * gk * gn)})
         x = layer.call(x, layer.params)
     jax.block_until_ready(x)
     return out

@@ -161,8 +161,8 @@ def grouped_tiles(group_sizes: jax.Array, *, bm: int, tiles: int
     row block it touches, in row order, empty groups not at all. Returns
     (``tile_group``, ``tile_block``, ``bounds``, ``live``): tile t computes
     group ``tile_group[t]`` on row block ``tile_block[t]``; tiles from
-    ``live[0]`` on repeat the last live tile's indices, so their blocks are
-    never fetched again, and do nothing."""
+    ``live[0]`` on repeat the last live tile's indices and do nothing (with
+    one K block, their blocks are never fetched again)."""
     sizes = group_sizes.astype(jnp.int32)
     ends = jnp.cumsum(sizes)
     starts = ends - sizes
@@ -177,6 +177,22 @@ def grouped_tiles(group_sizes: jax.Array, *, bm: int, tiles: int
     block = first[group] + t - (tile_end[group] - count[group])
     bounds = jnp.concatenate([jnp.zeros(1, jnp.int32), ends])
     return group, block, bounds, live[None]
+
+
+def grouped_weight_fetches(tile_group: jax.Array, live: jax.Array, *,
+                           gn: int, gk: int) -> jax.Array:
+    """The weight blocks a grouped launch's live tiles fetch, on the device.
+
+    The grid (``gn`` N blocks, row tiles, ``gk`` K blocks) fetches a weight
+    block whenever a live tile's index (group, K block, N block) differs
+    from the previous grid step's. With ``gk`` > 1 the K block changes at
+    every step; with ``gk`` = 1 only a tile that starts its group does."""
+    t = jnp.arange(tile_group.shape[0], dtype=jnp.int32)
+    fetch = t < live[0]
+    if gk == 1:
+        fetch &= jnp.concatenate([jnp.ones(1, bool),
+                                  tile_group[1:] != tile_group[:-1]])
+    return gn * gk * jnp.sum(fetch)
 
 
 def _group_rows(tile_group, tile_block, bounds, t, shape, bm: int

@@ -19,12 +19,19 @@ Traffic for C[M,N] = A[M,K] @ B[K,N] with grid (M/bm, N/bn, K/bk):
 
 A grouped GEMM (``groups`` = G > 1: M rows sorted by group, one K x N weight
 per group) runs at most ceil(M/bm) + G - 1 row tiles: each boundary between
-two groups inside a row tile costs one more, partial, tile. Each row tile
-reads its group's weights, and each partial tile re-reads up to bm rows of
-A; C is written once (a shared row block stays resident between its groups):
+two groups inside a row tile costs one more, partial, tile. Each partial
+tile re-reads up to bm rows of A; C is written once (a shared row block
+stays resident between its groups). The launch walks a group's row tiles
+one after another, within one N block. With more than one K block
+(bk < K), the weight block changes on every k-step, so each row tile reads
+its group's weights again. With one K block (bk = K), the group's
+consecutive tiles ask for the same weight block, which the pipeline does
+not fetch again: each group's weights are read once per N block. All of
+these are the worst case over group sizes:
 
   A reads:  ceil(N/bn) * (M + (G - 1) * bm) * K
-  B reads:  (ceil(M/bm) + G - 1) * K * N
+  B reads:  (ceil(M/bm) + G - 1) * K * N    (bk < K)
+            G * K * N                       (bk = K)
 """
 
 from __future__ import annotations
@@ -79,7 +86,7 @@ def matmul_traffic(m: int, n: int, k: int, blocks, controller="active",
     gj = math.ceil(n / blocks.bn)
     gk = math.ceil(k / blocks.bk)
     a_reads = gj * m * k + gj * (groups - 1) * blocks.bm * k
-    b_reads = gi * k * n
+    b_reads = (groups if groups > 1 and gk == 1 else gi) * k * n
     if controller is Controller.ACTIVE:
         c_traffic = m * n
     else:
@@ -139,7 +146,7 @@ def matmul_traffic_grid(m: int, n: int, k: int, bm, bn, bk,
     gj = -(-n // bn)
     gk = -(-k // bk)
     a_reads = gj * (m * k) + gj * ((groups - 1) * k) * bm
-    b_reads = gi * (k * n)
+    b_reads = (np.where(gk == 1, groups, gi) if groups > 1 else gi) * (k * n)
     if controller is Controller.ACTIVE:
         c_traffic = np.full_like(a_reads, m * n)
     else:

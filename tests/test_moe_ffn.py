@@ -4,7 +4,8 @@
 both controllers; `moe_layer` against the plain `moe_layer_ref` under
 uniform and skewed routing, and the tolerance catching a layer that leaves
 out its shared expert or a routed pick; the planner's ``groups`` (unchanged
-plans at ``groups=1``, the grouped traffic by hand); scalar-prefetch launches
+plans at ``groups=1``, the grouped traffic by hand, the weight reads the
+model charges against those the launch makes); scalar-prefetch launches
 through `launch.run` and the static checker's treatment of them.
 """
 
@@ -25,9 +26,11 @@ from repro.check.kernels import check_launch
 from repro.kernels import launch, moe_ffn
 from repro.kernels.psum_matmul import (grouped_matmul_launch_plan,
                                        grouped_row_tiles, grouped_tiles,
+                                       grouped_weight_fetches,
                                        psum_grouped_matmul)
 from repro.kernels.ref import moe_layer_ref
-from repro.plan.gemm_model import matmul_traffic, plan_matmul_blocks_scalar
+from repro.plan.gemm_model import (matmul_traffic, matmul_traffic_grid,
+                                   plan_matmul_blocks_scalar)
 
 KEY = jax.random.PRNGKey(15)
 
@@ -145,8 +148,14 @@ def test_the_checker_never_proves_a_prefetch_launch():
 
 
 # --------------------------------------------------------- the planner
-@pytest.mark.parametrize("m,n,k", [(2048, 8960, 1536), (512, 384, 640),
-                                   (300, 1000, 77)])
+@pytest.mark.parametrize("m,n,k", [
+    (2048, 8960, 1536), (512, 384, 640), (300, 1000, 77),
+    (2048, 1536, 8960),             # Qwen2-1.5B down (gate and up above)
+    (2048, 10944, 2048),            # DeepSeek-V2-Lite dense gate and up
+    (2048, 2048, 10944),            # ... dense down
+    (2048, 2816, 2048),             # ... shared experts' gate and up
+    (2048, 2048, 2816),             # ... shared experts' down
+])
 @pytest.mark.parametrize("controller", ["active", "passive"])
 def test_an_ungrouped_plan_is_unchanged(m, n, k, controller):
     wl = plan.MatmulWorkload(m=m, n=n, k=k)
@@ -161,23 +170,96 @@ def test_an_ungrouped_plan_is_unchanged(m, n, k, controller):
     assert got.traffic.interconnect_words == gj * m * k + gi * k * n + c
 
 
-def test_grouped_traffic_by_hand():
-    """DeepSeek-V2-Lite's expert up-projection: 12288 routed rows, 64
-    experts. Worst case 24 + 63 row tiles of 512, each reading its expert's
-    2048 x 1408 weight; 63 partial tiles re-read 512 rows of x."""
-    t = matmul_traffic(12288, 1408, 2048,
-                       _schedule("active", 512, 1408, 128), "active", 64)
-    assert t["a_reads"] == (12288 + 63 * 512) * 2048
-    assert t["b_reads"] == (24 + 63) * 2048 * 1408
-    assert t["c_traffic"] == 12288 * 1408
-    p = plan.plan(plan.MatmulWorkload(m=12288, k=2048, n=1408, groups=64),
-                  strategy="exhaustive_vmem", controller="active")
-    s = p.schedule
-    assert 1408 % s.bn == 0 and 2048 % s.bk == 0        # weights unpadded
-    assert (s.bm, s.bn) == (512, 1408)
-    assert p.traffic.interconnect_words == t["total"]
-    assert p.traffic.bytes == 2 * (t["a_reads"] + t["b_reads"]
-                                   + t["c_traffic"])
+@pytest.mark.parametrize("m,k,n,blocks,weight_reads", [
+    (12288, 2048, 1408, (512, 1408, 128), 24 + 63),
+    (12288, 2048, 1408, (128, 1408, 2048), 64),
+    (12288, 1408, 2048, (128, 2048, 1408), 64),
+], ids=["up-bk128", "up-bk2048", "down-bk1408"])
+def test_grouped_traffic_by_hand(m, k, n, blocks, weight_reads):
+    """DeepSeek-V2-Lite's expert GEMMs: 12288 routed rows, 64 experts.
+    With 16 K blocks, each of the worst case's 24 + 63 row tiles of 512
+    reads its expert's weight; with one K block, each expert's weight is
+    read once. 63 partial tiles re-read bm rows of x."""
+    bm, bn, bk = blocks
+    t = matmul_traffic(m, n, k, _schedule("active", *blocks), "active", 64)
+    assert t["a_reads"] == (n // bn) * (m + 63 * bm) * k
+    assert t["b_reads"] == weight_reads * k * n
+    assert t["c_traffic"] == m * n
+    for controller in ("active", "passive"):
+        grid = matmul_traffic_grid(m, n, k, [bm], [bn], [bk], controller, 64)
+        assert {key: float(v[0]) for key, v in grid.items()} == \
+            matmul_traffic(m, n, k, _schedule(controller, *blocks),
+                           controller, 64)
+
+
+@pytest.mark.parametrize("controller", ["active", "passive"])
+def test_the_planner_reads_each_experts_weight_once(controller):
+    """At bk = K the weight reads fall to one per expert, and small row
+    tiles then cost the least: (128, ., K) for both expert GEMMs."""
+    got = moe_ffn.moe_schedules(2048, 2048, 1408, 64, 6, 2816,
+                                controller=controller)
+    for name, (k, n), want in [("expert_up", (2048, 1408), (128, 1408, 2048)),
+                               ("expert_down", (1408, 2048),
+                                (128, 2048, 1408))]:
+        s = got[name]
+        assert (s.bm, s.bn, s.bk) == want, name
+        wl = plan.MatmulWorkload(m=12288, k=k, n=n, groups=64)
+        p = plan.plan(wl, strategy="exhaustive_vmem", controller=controller)
+        t = matmul_traffic(12288, n, k, s, controller, 64)
+        assert t["b_reads"] == 64 * k * n
+        assert p.traffic.interconnect_words == t["total"]
+    up = plan.plan(plan.MatmulWorkload(m=12288, k=2048, n=1408, groups=64),
+                   strategy="exhaustive_vmem", controller="active")
+    t = matmul_traffic(12288, 1408, 2048, up.schedule, "active", 64)
+    assert up.traffic.bytes == 2 * (t["a_reads"] + t["b_reads"]
+                                    + t["c_traffic"])
+
+
+def _walk_weight_fetches(sizes, *, k, n, bm, bn, bk):
+    """Walk the grouped launch's grid in order through its own ``w`` index
+    map and the device tile map; count the live steps whose weight block
+    differs from the previous step's (the ones the pipeline fetches)."""
+    p = grouped_matmul_launch_plan(rows=sum(sizes), k=k, n=n,
+                                   groups=len(sizes), bm=bm, bn=bn, bk=bk)
+    meta = [np.asarray(a) for a in grouped_tiles(
+        jnp.asarray(sizes, jnp.int32), bm=bm, tiles=p.grid[1])]
+    live = int(meta[3][0])
+    w_map = next(op for op in p.inputs if op.name == "w").index_map
+    fetches, prev = 0, None
+    for j in range(p.grid[0]):
+        for t in range(p.grid[1]):
+            for kk in range(p.grid[2]):
+                idx = tuple(int(i) for i in w_map(j, t, kk, *meta))
+                fetches += t < live and idx != prev
+                prev = idx
+    return fetches, live, p.grid
+
+
+@pytest.mark.parametrize("sizes", [
+    (300, 0, 37, 0, 500, 12, 0, 175),   # skewed, empty experts between
+    (0, 0, 0, 1024),                     # one expert takes every row
+    (5, 700, 3, 3, 3, 300, 0, 10),       # tiny experts inside one tile
+], ids=["skewed", "one", "tiny"])
+@pytest.mark.parametrize("blocks", [(128, 128, 256), (128, 256, 256),
+                                    (128, 128, 128), (256, 256, 64)],
+                         ids=["gk1-gn2", "gk1-gn1", "gk2-gn2", "gk4-gn1"])
+def test_the_model_bounds_the_weight_reads_the_launch_makes(sizes, blocks):
+    bm, bn, bk = blocks
+    k = n = 256
+    fetches, live, (gn, _, gk) = _walk_weight_fetches(
+        sizes, k=k, n=n, bm=bm, bn=bn, bk=bk)
+    model = matmul_traffic(sum(sizes), n, k, _schedule("active", *blocks),
+                           "active", len(sizes))["b_reads"] / (bk * bn)
+    experts = sum(1 for r in sizes if r)
+    if gk == 1:
+        assert fetches == experts * gn <= model
+    else:
+        assert fetches == live * gk * gn <= model
+    tile_group, _, _, live_dev = grouped_tiles(
+        jnp.asarray(sizes, jnp.int32), bm=bm,
+        tiles=grouped_row_tiles(sum(sizes), bm, len(sizes)))
+    assert int(grouped_weight_fetches(tile_group, live_dev, gn=gn,
+                                      gk=gk)) == fetches
 
 
 # ---------------------------------------------------------- the layer
@@ -241,6 +323,33 @@ def test_moe_layer_matches_the_reference(skewed):
     assert stats["mean_rows"] == T * K / E
     assert (stats["max_rows"] / stats["mean_rows"] > 1.8) == skewed
     assert stats["tile_rows_per_row"] >= 1.0
+
+
+@pytest.mark.parametrize("expert_up", [None, (128, 128, 128)],
+                         ids=["planned", "bk128"])
+def test_routing_stats_count_the_weight_reads_of_the_up_launch(expert_up):
+    """``weight_reads_per_expert`` is the walked launch's fetches over one
+    read of every expert that receives rows: 1 at the planned bk = K, the
+    live tiles over the experts with rows at two K blocks."""
+    params, x, schedules, _ = _layer_case(True)
+    if expert_up is not None:
+        schedules = dict(schedules, expert_up=_schedule("active", *expert_up))
+    up = schedules["expert_up"]
+    stats = moe_ffn.routing_stats(
+        x, [moe_ffn.ffn_layer("moe", params, schedules, eps=1e-6, top_k=K)],
+        eps=1e-6)[0]
+    _, picks = moe_ffn.route(moe_ffn.rmsnorm(x, 1e-6).astype(jnp.bfloat16),
+                             params["router"], K)
+    sizes = np.bincount(np.asarray(picks).ravel(), minlength=E).tolist()
+    fetches, live, (gn, _, gk) = _walk_weight_fetches(
+        sizes, k=D, n=F, bm=up.bm, bn=up.bn, bk=up.bk)
+    experts = sum(1 for r in sizes if r)
+    assert stats["weight_reads_per_expert"] == fetches / (experts * gk * gn)
+    assert stats["tile_rows_per_row"] == live * up.bm / (T * K)
+    if expert_up is None:
+        assert up.bk == D and stats["weight_reads_per_expert"] == 1.0
+    else:
+        assert stats["weight_reads_per_expert"] == live / experts > 1.0
 
 
 @pytest.mark.parametrize("left_out", ["shared", "last_pick"])

@@ -106,11 +106,20 @@ EXPERT_UP = plan.MatmulWorkload(m=12288, n=1408, k=2048, groups=64)
 # DeepSeek-V2-Lite: 2048 tokens x top-6 routed rows over 64 experts of 1408
 
 
-@pytest.mark.parametrize("controller", ["active", "passive"])
-def test_psum_grouped_matmul_compiles(one_chip, controller):
+@pytest.mark.parametrize("controller,blocks", [
+    ("active", None),
+    ("passive", None),
+    ("passive", (512, 1408, 128)),   # k > 0 launches: the aliased read-back
+], ids=["active", "passive", "passive-bk128"])
+def test_psum_grouped_matmul_compiles(one_chip, controller, blocks):
     from repro.kernels.psum_matmul import psum_grouped_matmul
-    sched = plan.plan(EXPERT_UP, strategy="exhaustive_vmem",
-                      controller=controller).schedule
+    if blocks is None:
+        sched = plan.plan(EXPERT_UP, strategy="exhaustive_vmem",
+                          controller=controller).schedule
+    else:
+        sched = plan.Schedule(kind="matmul", bm=blocks[0], bn=blocks[1],
+                              bk=blocks[2],
+                              controller=plan.Controller(controller))
     wl = EXPERT_UP
     _compile(psum_grouped_matmul, ((wl.m, wl.k), jnp.bfloat16),
              ((wl.groups, wl.k, wl.n), jnp.bfloat16),
