@@ -11,7 +11,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import plan
-from repro.kernels.psum_matmul import hbm_traffic_bytes, psum_matmul
+from repro.kernels.psum_matmul import psum_matmul
+from repro.plan.gemm_model import matmul_terms
 
 GEMMS = [
     ("ffn_up_8k", 8192, 28672, 8192),      # llama-90b FFN
@@ -24,11 +25,11 @@ GEMMS = [
 def traffic_rows() -> list[str]:
     rows = []
     for name, m, n, k in GEMMS:
-        sched = plan.plan(plan.MatmulWorkload(name=name, m=m, n=n, k=k),
-                          strategy="exhaustive_vmem", controller="active").schedule
-        kw = dict(bm=sched.bm, bn=sched.bn, bk=sched.bk)
-        act = hbm_traffic_bytes(m, n, k, controller="active", **kw)
-        pas = hbm_traffic_bytes(m, n, k, controller="passive", **kw)
+        wl = plan.MatmulWorkload(name=name, m=m, n=n, k=k)
+        s = plan.plan(wl, strategy="exhaustive_vmem",
+                      controller="active").schedule
+        act = float(matmul_terms(wl, s.bm, s.bn, s.bk, "active").bytes)
+        pas = float(matmul_terms(wl, s.bm, s.bn, s.bk, "passive").bytes)
         saving = 100 * (1 - act / pas)
         rows.append(f"kernel_traffic/{name}/active_GB,0,{act/1e9:.3f}")
         rows.append(f"kernel_traffic/{name}/passive_GB,0,{pas/1e9:.3f}")

@@ -12,7 +12,7 @@ import time
 
 from repro import plan
 from repro.core.cnn_zoo import PAPER_CNNS, PAPER_TABLE3
-from repro.plan import conv_model, dse
+from repro.plan import dse
 from repro.plan.schedule import Controller
 
 P_TABLE1 = (512, 2048, 16384)
@@ -97,40 +97,6 @@ def beyond_exact_search() -> list[str]:
         us = rp["us_per_call"] + re_["us_per_call"]
         rows.append(f"beyond/exact_vs_eq7/{rp['network']}/P{rp['budget']}"
                     f",{us:.0f},{gain:.2f}")
-    return rows
-
-
-def dse_speedup(repeats: int = 5) -> list[str]:
-    """Exact-search speedup: the frozen per-candidate scalar loop vs the
-    vectorized one-shot network batch (`conv_exact_search_batch`), per MAC
-    budget on ResNet-18, plus the across-budgets ResNet-18 total. derived =
-    speedup factor for the ``speedup`` rows, achieved traffic (M activations)
-    otherwise."""
-    rows = []
-    nets = ("resnet18",)
-    total_scalar = total_vec = 0.0
-    for net in nets:
-        wls = plan.conv_workloads(net)
-        for p in P_TABLE1:
-            t_scalar = min(_timed(lambda: [
-                conv_model.plan_conv_exact_scalar(w, p, Controller.PASSIVE)
-                for w in wls])[1] for _ in range(repeats))
-            t_vec = min(_timed(lambda: conv_model.conv_exact_search_batch(
-                wls, p, Controller.PASSIVE))[1] for _ in range(repeats))
-            scalar_mn = [conv_model.plan_conv_exact_scalar(
-                w, p, Controller.PASSIVE) for w in wls]
-            vec_mn = conv_model.conv_exact_search_batch(
-                wls, p, Controller.PASSIVE)
-            assert scalar_mn == vec_mn, "vectorized argmin diverged from loop"
-            traffic = plan.network_traffic(wls, p, "exact_opt") / 1e6
-            total_scalar += t_scalar
-            total_vec += t_vec
-            rows.append(f"dse/exact_scalar/{net}/P{p},{t_scalar:.0f},{traffic:.2f}")
-            rows.append(f"dse/exact_vectorized/{net}/P{p},{t_vec:.0f},{traffic:.2f}")
-            rows.append(f"dse/speedup/{net}/P{p},{t_vec:.0f},"
-                        f"{t_scalar / t_vec:.1f}")
-    rows.append(f"dse/speedup/resnet18/total,{total_vec:.0f},"
-                f"{total_scalar / total_vec:.1f}")
     return rows
 
 

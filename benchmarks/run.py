@@ -9,8 +9,6 @@ trajectories can be recorded as ``BENCH_*.json`` artifacts. Sections:
   table3  — Table III (minimum bandwidth) + deviation vs paper
   fig2    — Fig. 2    (% saving of the active controller)
   beyond  — beyond-paper exact-search gains
-  dse     — exact-search speedup: scalar loop vs vectorized argmin
-            (the rows committed as BENCH_plan.json)
   pareto  — MAC-budget-vs-traffic Pareto frontier per CNN
   netplan — network-graph planning: no_fusion vs fused-residency totals
             per zoo CNN (with --json, also written to BENCH_netplan.json)
@@ -187,7 +185,6 @@ def main(argv: list[str] | None = None) -> None:
         "table3": paper_tables.table3,
         "fig2": paper_tables.fig2,
         "beyond": paper_tables.beyond_exact_search,
-        "dse": paper_tables.dse_speedup,
         "pareto": paper_tables.dse_pareto,
         "netplan": functools.partial(paper_tables.netplan_savings,
                                      smoke=smoke),

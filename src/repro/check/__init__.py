@@ -16,7 +16,7 @@ codes):
     the words the kernels actually move equal the analytical model.
   * **Codebase lint** (`check_codebase`, rules in ``tools/check_rules.py``):
     AST rules keeping words-vs-bytes conversions, energy constants, raw
-    ``pallas_call`` escapes, and deprecated shims where they belong.
+    ``pallas_call`` escapes and wall-clock reads where they belong.
 
 CLI: ``python -m repro.check [--plans] [--codebase] [--dataflow]
 [--github]``.

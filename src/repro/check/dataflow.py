@@ -51,7 +51,7 @@ the abstract trace is a function of the kernel *code*, not the grid sizes —
 grids only enter through guard constants and axis extents. So one trace per
 degeneracy class (which grid axes are 1) validates the structure, and the
 trace-derived counting formulas are then evaluated as numpy arrays over the
-whole candidate set against `conv_bandwidth_grid` / `matmul_traffic_grid`,
+whole candidate set against `conv_terms` / `matmul_terms`,
 certifying every admitted candidate of a search space in one call.
 
 Everything here is pure Python + numpy until a kernel module is imported
@@ -63,7 +63,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import itertools
-import math
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -605,7 +604,7 @@ def _sum_words(parts: Sequence[RefWords]) -> RefWords:
 def matmul_dataflow(wl: MatmulWorkload, schedule: Schedule,
                     subject: Optional[str] = None) -> DataflowReport:
     """Prove `psum_matmul` under ``schedule`` moves exactly the words
-    `gemm_model.matmul_traffic` charges, for either controller.
+    `gemm_model.matmul_terms` charges, for either controller.
 
     The passive GEMM is one launch per k-step; every launch is traced and
     the words summed. Its partial sums are the step-0 output plus, at each
@@ -613,7 +612,7 @@ def matmul_dataflow(wl: MatmulWorkload, schedule: Schedule,
     their (gk, gk - 1) write/read chain is counted from real HBM transfers,
     never from output read-backs (Pallas issues none)."""
     from repro.check.kernels import check_matmul_launch
-    from repro.plan.gemm_model import matmul_traffic
+    from repro.plan.gemm_model import matmul_terms
     ctrl = schedule.controller.value
     subject = subject or f"dataflow/{wl.name}/{ctrl}"
     if wl.groups > 1:
@@ -627,9 +626,9 @@ def matmul_dataflow(wl: MatmulWorkload, schedule: Schedule,
         return DataflowReport(subject, tuple(geo), {})
     from repro.kernels.psum_matmul import (matmul_launch_plan,
                                            reduction_launches)
-    model = matmul_traffic(wl.m, wl.n, wl.k, schedule, schedule.controller)
+    model = matmul_terms(wl, schedule.bm, schedule.bn, schedule.bk,
+                         schedule.controller)
     acc_real = wl.m * wl.n
-    gk = math.ceil(wl.k / schedule.bk)
     diags: List[Diagnostic] = []
     parts: Dict[str, List[RefWords]] = {}
     acc_w = acc_r = 0
@@ -664,14 +663,14 @@ def matmul_dataflow(wl: MatmulWorkload, schedule: Schedule,
             diags.append(Diagnostic("RPC046", subject, str(exc)))
             return DataflowReport(subject, tuple(geo + diags), {})
     words = {name: _sum_words(p) for name, p in parts.items()}
-    if words["x"].compute_reads != int(model["a_reads"]):
+    if words["x"].compute_reads != int(model.a_reads):
         diags.append(_mismatch(subject, "A reads vs x loads",
                                words["x"].compute_reads,
-                               int(model["a_reads"])))
-    if words["w"].compute_reads != int(model["b_reads"]):
+                               int(model.a_reads)))
+    if words["w"].compute_reads != int(model.b_reads):
         diags.append(_mismatch(subject, "B reads vs w loads",
                                words["w"].compute_reads,
-                               int(model["b_reads"])))
+                               int(model.b_reads)))
     hbm_c = words["out"].hbm_writes + words["out"].hbm_reads
     if ctrl == "active":
         c_derived = hbm_c
@@ -683,15 +682,15 @@ def matmul_dataflow(wl: MatmulWorkload, schedule: Schedule,
             diags.append(_mismatch(
                 subject, "passive C: HBM round-trips vs the RMW chain",
                 hbm_c, c_derived))
-    if c_derived != int(model["c_traffic"]):
+    if c_derived != int(model.c_traffic):
         diags.append(_mismatch(subject, "C traffic vs accumulator RMW",
-                               c_derived, int(model["c_traffic"])))
-    if (acc_w, acc_r) != (gk * acc_real, (gk - 1) * acc_real):
+                               c_derived, int(model.c_traffic)))
+    meter = (int(model.sram_writes), int(model.sram_reads))
+    if (acc_w, acc_r) != meter:
         diags.append(Diagnostic(
             "RPC043", subject,
             f"accumulator RMW counts (writes {acc_w}, reads {acc_r}) "
-            f"disagree with the meter ({gk * acc_real}, "
-            f"{(gk - 1) * acc_real})"))
+            f"disagree with the meter {meter}"))
     for nm in ("x", "w"):
         if words[nm].hbm_reads > words[nm].hbm_model:
             diags.append(_mismatch(subject, f"{nm} HBM fetches exceed model",
@@ -786,8 +785,8 @@ def certify_conv_space(wl: ConvWorkload, budget: Optional[int] = None,
                        space=None) -> SpaceCertificate:
     """Certify every candidate a conv search space admits for ``wl``: the
     traced kernel structure (one trace per degeneracy class) plus the
-    vectorized counting formulas against `conv_bandwidth_grid`."""
-    from repro.plan.conv_model import conv_bandwidth_grid
+    vectorized counting formulas against `conv_terms`."""
+    from repro.plan.conv_model import conv_terms
     from repro.plan.space import ConvExactSpace
     controller = Controller.coerce(controller)
     subject = f"certify/{wl.name}/{controller.value}"
@@ -831,8 +830,8 @@ def certify_conv_space(wl: ConvWorkload, budget: Optional[int] = None,
     acc_w = (n_ci * wl.out_acts).astype(np.float64)
     acc_r = ((n_ci - 1) * wl.out_acts).astype(np.float64)
     b_o_d = acc_w if controller is Controller.ACTIVE else acc_w + acc_r
-    b_i_m, b_o_m = conv_bandwidth_grid(wl, m, n, controller,
-                                       exact_iters=True)
+    model = conv_terms(wl, m, n, controller)
+    b_i_m, b_o_m = model.b_i, model.b_o
     for name, dv, mv in (("B_i (eq 2)", b_i_d, b_i_m),
                          ("B_o (eq 3)", b_o_d, b_o_m)):
         bad = np.nonzero(dv != mv)[0]
@@ -862,9 +861,9 @@ def certify_matmul_space(wl: MatmulWorkload, budget: Optional[int] = None,
                          controller: "Controller | str" = Controller.ACTIVE,
                          space=None) -> SpaceCertificate:
     """Certify every VMEM-admitted candidate of a GEMM block space against
-    `matmul_traffic_grid`, for either controller."""
+    `matmul_terms`, for either controller."""
     from repro.plan.dse import VmemBudget
-    from repro.plan.gemm_model import DEFAULT_VMEM_BUDGET, matmul_traffic_grid
+    from repro.plan.gemm_model import DEFAULT_VMEM_BUDGET, matmul_terms
     from repro.plan.space import AlignedBlockSpace
     controller = Controller.coerce(controller)
     subject = f"certify/{wl.name}/{controller.value}"
@@ -872,6 +871,11 @@ def certify_matmul_space(wl: MatmulWorkload, budget: Optional[int] = None,
         budget = DEFAULT_VMEM_BUDGET
     if space is None:
         space = AlignedBlockSpace()
+    if wl.groups > 1:
+        return SpaceCertificate(subject, "matmul", controller.value, 0, 0, 0, (
+            Diagnostic("RPC046", subject,
+                       "grouped GEMM: its row tiles map to groups on the "
+                       "device; space not kernel-certifiable"),))
     cands = space(wl, int(budget))
     admitted = VmemBudget()(wl, cands, int(budget))
     bm = np.asarray(cands.bm, np.int64)[admitted]
@@ -894,7 +898,7 @@ def certify_matmul_space(wl: MatmulWorkload, budget: Optional[int] = None,
     if errors(diags):
         return SpaceCertificate(subject, "matmul", controller.value,
                                 int(bm.size), 0, 0, tuple(diags))
-    t = matmul_traffic_grid(wl.m, wl.n, wl.k, bm, bn, bk, controller)
+    t = matmul_terms(wl, bm, bn, bk, controller)
     a_d = (gj * (wl.m * wl.k)).astype(np.float64)
     b_d = (gi * (wl.k * wl.n)).astype(np.float64)
     acc = wl.m * wl.n
@@ -902,9 +906,9 @@ def certify_matmul_space(wl: MatmulWorkload, budget: Optional[int] = None,
         c_d = np.full_like(a_d, float(acc))
     else:     # gk launches: gk psum writes, gk - 1 read-backs, all via HBM
         c_d = ((2 * gk - 1) * acc).astype(np.float64)
-    for name, dv, mv in (("A reads", a_d, t["a_reads"]),
-                         ("B reads", b_d, t["b_reads"]),
-                         ("C traffic", c_d, t["c_traffic"])):
+    for name, dv, mv in (("A reads", a_d, t.a_reads),
+                         ("B reads", b_d, t.b_reads),
+                         ("C traffic", c_d, t.c_traffic)):
         bad = np.nonzero(dv != mv)[0]
         if bad.size:
             i = int(bad[0])

@@ -198,9 +198,6 @@ _register("RPL105", "bare-except", Severity.ERROR,
           "swallows faults the degradation layer must dispatch on",
           "catch a typed repro.errors exception (PlanError, BudgetError, "
           "DeadlineExceeded, Shed) or re-raise")
-_register("RPL110", "deprecated-import", Severity.WARNING,
-          "import of the deprecated core.bwmodel / core.partitioner shims",
-          "import from repro.plan (conv_model / gemm_model) instead")
 
 
 @dataclasses.dataclass(frozen=True)

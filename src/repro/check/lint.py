@@ -5,7 +5,7 @@ The verifier (layer 1) proves individual IR objects; this layer proves the
 
   * RPL100 — words are the model currency; multiplying by a dtype width
     (``word_bytes`` / ``in_bytes`` / ``out_bytes`` / ``acc_bytes``) is a unit
-    conversion and belongs only in the byte-model modules (``plan.traffic``,
+    conversion and belongs only in the byte-model modules (``plan.conv_model``,
     ``plan.gemm_model``, ``sim``, ...). Everywhere else consumes
     ``TrafficReport.bytes`` / ``Tensor.nbytes``.
   * RPL101 — per-access energy constants live in ``roofline/constants.py``
@@ -20,8 +20,6 @@ The verifier (layer 1) proves individual IR objects; this layer proves the
     in ``repro.obs``, ``benchmarks/`` and the planserve load generator;
     everywhere else measures through ``obs.Stopwatch`` so the interval can
     double as a trace span.
-  * RPL110 — the pre-`repro.plan` shims (``repro.core.bwmodel``,
-    ``repro.core.partitioner``) are deprecated import surfaces.
 
 Rules are plain data (`LintRule`): a predicate over the repo-relative path
 plus an AST visitor returning `Diagnostic`s. The repo's concrete rule set —
@@ -46,11 +44,10 @@ WIDTH_NAMES = frozenset(
 
 #: modules allowed to convert words -> bytes (repo-relative glob patterns)
 BYTE_MODEL_MODULES = (
-    "src/repro/plan/traffic.py",
+    "src/repro/plan/conv_model.py",
     "src/repro/plan/gemm_model.py",
     "src/repro/plan/graph.py",
     "src/repro/plan/netplan.py",
-    "src/repro/plan/objectives.py",
     "src/repro/plan/schedule.py",
     "src/repro/plan/workload.py",
     "src/repro/sim/*",
@@ -64,9 +61,6 @@ ENERGY_CONSTANT_HOME = ("src/repro/roofline/constants.py",)
 #: the only package that may call pl.pallas_call — everything else goes
 #: through a LaunchPlan so the dataflow analyzer sees the launch that runs
 KERNEL_LAUNCH_HOME = ("src/repro/kernels/*",)
-
-DEPRECATED_MODULES = ("repro.core.bwmodel", "repro.core.partitioner")
-DEPRECATED_IMPORT_OK = ("src/repro/core/*",)
 
 #: the only homes for raw wall-clock reads: the tracing package itself,
 #: benchmark harnesses, and the planner-service load generator (it wall-times
@@ -278,39 +272,9 @@ def bare_except_rule(
     return LintRule("RPL105", _visit_bare_except, tuple(allowed))
 
 
-# --------------------------------------------------------------- RPL110
-def _visit_deprecated_import(tree: ast.Module, rel: str) -> List[Diagnostic]:
-    out: List[Diagnostic] = []
-    for node in ast.walk(tree):
-        hit: Optional[str] = None
-        if isinstance(node, ast.Import):
-            for alias in node.names:
-                if alias.name in DEPRECATED_MODULES:
-                    hit = alias.name
-        elif isinstance(node, ast.ImportFrom) and node.module:
-            if node.module in DEPRECATED_MODULES:
-                hit = node.module
-            elif node.module == "repro.core":
-                bad = {a.name for a in node.names} & {"bwmodel", "partitioner"}
-                if bad:
-                    hit = f"repro.core.{bad.pop()}"
-        if hit:
-            out.append(Diagnostic(
-                "RPL110", rel,
-                f"import of deprecated shim {hit}",
-                file=rel, line=node.lineno))
-    return out
-
-
-def deprecated_import_rule(
-        allowed: Sequence[str] = DEPRECATED_IMPORT_OK) -> LintRule:
-    return LintRule("RPL110", _visit_deprecated_import, tuple(allowed))
-
-
 def default_rules() -> List[LintRule]:
     return [raw_byte_arith_rule(), magic_energy_rule(), cross_assign_rule(),
-            raw_pallas_rule(), adhoc_timing_rule(), bare_except_rule(),
-            deprecated_import_rule()]
+            raw_pallas_rule(), adhoc_timing_rule(), bare_except_rule()]
 
 
 # ----------------------------------------------------------------- driver

@@ -4,34 +4,19 @@ the active memory controller, plus the TPU-native generalization to matmul
 block tiling.
 
 The planning implementation lives in ``repro.plan`` (one Workload ->
-Schedule -> Execution pipeline); this package keeps the paper-domain pieces
-and the legacy shims:
+Schedule -> Execution pipeline); this package keeps the paper-domain pieces:
 
   cnn_zoo.py      the paper's eight CNNs as programmatic layer tables
   amc.py          executable, instrumented active-memory-controller model
                   (executes + validates ``repro.plan`` Schedules)
   planner.py      whole-network partition schedules (wraps ``plan.plan_many``)
-  bwmodel.py      DEPRECATED shim over ``repro.plan.conv_model``
-  partitioner.py  DEPRECATED shim over ``repro.plan.gemm_model``
 """
 
-from repro.core.bwmodel import (CONTROLLERS, STRATEGIES, Partition,
-                                layer_bandwidth, min_bandwidth,
-                                network_bandwidth, network_table,
-                                optimal_m_realvalued, partition_layer)
 from repro.core.cnn_zoo import (PAPER_CNNS, PAPER_TABLE3, ConvLayer, get_cnn,
                                 get_cnn_graph_spec)
-from repro.core.partitioner import (MatmulBlocks, first_order_block,
-                                    matmul_traffic, plan_matmul_blocks,
-                                    traffic_model_bytes)
 from repro.core.planner import NetworkPlan, plan_network
 
 __all__ = [
-    "CONTROLLERS", "STRATEGIES", "Partition", "layer_bandwidth",
-    "min_bandwidth", "network_bandwidth", "network_table",
-    "optimal_m_realvalued", "partition_layer", "PAPER_CNNS", "PAPER_TABLE3",
-    "ConvLayer", "get_cnn", "get_cnn_graph_spec",
-    "MatmulBlocks", "first_order_block",
-    "matmul_traffic", "plan_matmul_blocks", "traffic_model_bytes",
+    "PAPER_CNNS", "PAPER_TABLE3", "ConvLayer", "get_cnn", "get_cnn_graph_spec",
     "NetworkPlan", "plan_network",
 ]

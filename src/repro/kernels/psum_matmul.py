@@ -428,15 +428,3 @@ def psum_matmul(x: jax.Array, w: jax.Array, *, schedule=None, bm: int = 256,
         # back — an extra HBM round-trip the active schedule fuses away.
         out = ACTIVATIONS[act](psums).astype(out_dtype)
     return out[:m, :n]
-
-
-def hbm_traffic_bytes(m: int, n: int, k: int, *, bm: int, bn: int, bk: int,
-                      controller: str, in_bytes: int = 2,
-                      out_bytes: int = 2) -> float:
-    """Analytical HBM traffic of the schedules above — the dtype-weighted
-    byte model lives in one place (`repro.plan.gemm_model`); this is a view
-    of it, not a second copy (passive spills are fp32 accumulators)."""
-    from repro.plan.gemm_model import MatmulBlocks, traffic_model_bytes
-    return traffic_model_bytes(m, n, k, MatmulBlocks(bm, bn, bk), controller,
-                               in_bytes=in_bytes, out_bytes=out_bytes,
-                               acc_bytes=4)

@@ -18,8 +18,9 @@ pipeline:
 Consumers: the Pallas kernels accept ``schedule=`` directly, the AMC
 simulator executes + cross-validates a `Schedule` against the analytical
 `TrafficReport`, and ``core.planner.plan_network`` is a thin wrapper over
-``plan_many``. The legacy ``core.bwmodel`` / ``core.partitioner`` modules are
-deprecation shims over this package.
+``plan_many``. The traffic model has one evaluator per workload kind,
+`conv_model.conv_terms` and `gemm_model.matmul_terms`; every report,
+objective and check reads it.
 """
 
 from repro.plan import dse, fleet, graph, netplan, objectives, space

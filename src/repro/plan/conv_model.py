@@ -1,8 +1,7 @@
 """The paper's first-order bandwidth model (eqs 1-7) over `ConvWorkload`.
 
-This is the single implementation of the analytical model; the legacy
-``core.bwmodel`` functions are thin shims over it. Semantics (and numbers)
-are identical to the seed implementation:
+This is the single implementation of the analytical model (`conv_terms`).
+Semantics (and numbers) are identical to the seed implementation:
 
   constraint (eq 1):  K^2 * m * n <= P
   input BW   (eq 2):  B_i = Wi*Hi*M * (N/n)          (re-read per output block)
@@ -10,12 +9,22 @@ are identical to the seed implementation:
   optimum    (eq 7):  m* = sqrt(2*Wo*Ho*P / (Wi*Hi*K^2)), snapped to a factor of M
 
 with the active-memory-controller variant of Section III (B_o = Wo*Ho*N * M/m)
-and per-group handling of grouped/depthwise convolutions.
+and per-group handling of grouped/depthwise convolutions (M/m and N/n count
+within one group). The accumulator SRAM meter counts whole passes in either
+iteration convention: every input word is read once per arrival, and the
+accumulator is written every pass and read on every pass but the first
+(internally when active, over the bus when passive):
+
+  SRAM reads:   B_i + (ceil(M/m) - 1) * Wo*Ho*N
+  SRAM writes:  ceil(M/m) * Wo*Ho*N
 """
 
 from __future__ import annotations
 
+import functools
 import math
+import types
+from typing import NamedTuple
 
 import numpy as np
 
@@ -35,26 +44,74 @@ def _snap_to_factor(value: float, total: int, cap: int) -> int:
     return min(cands, key=lambda f: (abs(f - value), f)) if cands else 1
 
 
-def conv_bandwidth(wl: ConvWorkload, m: int, n: int, controller: Controller,
-                   exact_iters: bool = False) -> tuple[float, float]:
-    """(B_i, B_o) in activations for one layer under an (m, n) partition.
+class ConvTerms(NamedTuple):
+    """`conv_terms`' result: iteration counts (int64 under ceil counts,
+    float64 under the paper's M/m) and float64 words and bytes, 0-d for one
+    schedule, one entry per candidate for arrays."""
 
-    `exact_iters=True` uses ceil(M/m) iteration counts (valid for any integer
-    m, n); False uses the paper's M/m with m a factor of M.
-    """
-    g = wl.groups
-    mg, ng = wl.cin // g, wl.cout // g
-    m = min(m, mg)
-    n = min(n, ng)
-    out_iters = math.ceil(ng / n) if exact_iters else ng / n
-    in_iters = math.ceil(mg / m) if exact_iters else mg / m
-    b_i = wl.wi * wl.hi * wl.cin * out_iters
-    writes = wl.wo * wl.ho * wl.cout * in_iters
-    if controller is Controller.ACTIVE:
-        b_o = writes                      # controller adds locally; write-only traffic
+    in_iters: np.ndarray     # accumulation passes, M/m
+    out_iters: np.ndarray    # output blocks, N/n: reads of each input word
+    b_i: np.ndarray          # eq (2)
+    b_o: np.ndarray          # eq (3), or its active variant
+    total: np.ndarray
+    sram_reads: np.ndarray
+    sram_writes: np.ndarray
+    bytes: np.ndarray        # interconnect words at the word width
+    sram_bytes: np.ndarray   # SRAM accesses at the word width
+
+    @property
+    def input_words(self) -> np.ndarray:
+        return self.b_i
+
+    @property
+    def output_words(self) -> np.ndarray:
+        return self.b_o
+
+    @property
+    def read_iters(self) -> np.ndarray:
+        return self.out_iters
+
+    @property
+    def weight_reads(self) -> np.ndarray:
+        """The paper's model charges no weight traffic."""
+        return np.zeros_like(self.b_i)
+
+
+def conv_terms(wl: ConvWorkload, m, n, controller: Controller,
+               exact_iters: bool = True) -> ConvTerms:
+    """Eqs (2)/(3) and the SRAM meter (module docstring) for one layer under
+    an (m, n) partition: scalars for one schedule or candidate arrays.
+
+    ``exact_iters=True`` uses ceil(M/m) iteration counts (valid for any
+    integer m, n) in exact int64 arithmetic with one final float64
+    conversion; False uses the paper's real-valued M/m. ``wl`` may also hold
+    per-candidate arrays in the fields read here (``cin``, ``cout``,
+    ``groups``, ``in_acts``, ``out_acts``, ``word_bytes``), which is how
+    `conv_exact_search_batch` evaluates a whole network at once."""
+    controller = Controller.coerce(controller)
+    mg, ng = wl.cin // wl.groups, wl.cout // wl.groups
+    m_eff = np.minimum(np.asarray(m, np.int64), mg)
+    n_eff = np.minimum(np.asarray(n, np.int64), ng)
+    passes = -(-mg // m_eff)
+    if exact_iters:
+        in_iters, out_iters = passes, -(-ng // n_eff)
     else:
-        b_o = 2 * writes - wl.wo * wl.ho * wl.cout  # + read-before-update
-    return float(b_i), float(b_o)
+        in_iters, out_iters = mg / m_eff, ng / n_eff
+    b_i = wl.in_acts * out_iters
+    writes = wl.out_acts * in_iters
+    if controller is Controller.ACTIVE:
+        b_o = writes                      # controller adds locally; write-only
+    else:
+        b_o = 2 * writes - wl.out_acts    # + read-before-update
+    sram_reads = b_i + (passes - 1) * wl.out_acts
+    sram_writes = passes * wl.out_acts
+    f64 = functools.partial(np.asarray, dtype=np.float64)
+    return ConvTerms(
+        in_iters=in_iters, out_iters=out_iters, b_i=f64(b_i), b_o=f64(b_o),
+        total=f64(b_i + b_o), sram_reads=f64(sram_reads),
+        sram_writes=f64(sram_writes),
+        bytes=f64((b_i + b_o) * wl.word_bytes),
+        sram_bytes=f64((sram_reads + sram_writes) * wl.word_bytes))
 
 
 def optimal_m_realvalued(wl: ConvWorkload, p_macs: int,
@@ -64,48 +121,6 @@ def optimal_m_realvalued(wl: ConvWorkload, p_macs: int,
     factor = 2.0 if controller is Controller.PASSIVE else 1.0
     return math.sqrt(factor * wl.wo * wl.ho * p_macs
                      / (wl.wi * wl.hi * wl.k * wl.k))
-
-
-def _bandwidth_terms(mg, ng, in_pref, out_pref, m, n,
-                     controller: Controller, exact_iters: bool):
-    """eqs (2)/(3) over candidate arrays — the one vectorized implementation
-    both `conv_bandwidth_grid` and `conv_exact_search_batch` evaluate.
-    ``mg``/``ng``/``in_pref``/``out_pref`` are per-group channel counts and
-    the Wi*Hi*M / Wo*Ho*N prefactors, scalars or per-candidate arrays."""
-    m_eff = np.minimum(m, mg)
-    n_eff = np.minimum(n, ng)
-    if exact_iters:
-        out_iters = -(-ng // n_eff)        # ceil on int64
-        in_iters = -(-mg // m_eff)
-    else:
-        out_iters = ng / n_eff             # the paper's real-valued convention
-        in_iters = mg / m_eff
-    b_i = in_pref * out_iters
-    writes = out_pref * in_iters
-    if controller is Controller.ACTIVE:
-        b_o = writes
-    else:
-        b_o = 2 * writes - out_pref
-    return b_i, b_o
-
-
-def conv_bandwidth_grid(wl: ConvWorkload, m, n, controller: Controller,
-                        exact_iters: bool = False
-                        ) -> tuple[np.ndarray, np.ndarray]:
-    """Vectorized `conv_bandwidth`: (B_i, B_o) float64 arrays over candidate
-    arrays ``m``/``n``. Element-for-element bit-identical to the scalar
-    evaluator — every intermediate is the same exact integer (or the same IEEE
-    division) the scalar path computes, just batched."""
-    m = np.asarray(m, dtype=np.int64)
-    n = np.asarray(n, dtype=np.int64)
-    g = wl.groups
-    b_i, b_o = _bandwidth_terms(
-        wl.cin // g, wl.cout // g,
-        wl.wi * wl.hi * wl.cin,            # exact Python ints, as in the
-        wl.wo * wl.ho * wl.cout,           # scalar path
-        m, n, controller, exact_iters)
-    return (np.asarray(b_i, dtype=np.float64),
-            np.asarray(b_o, dtype=np.float64))
 
 
 def conv_exact_candidates(wl: ConvWorkload, p_macs: int
@@ -150,30 +165,12 @@ def closed_form_mn(wl: ConvWorkload, p_macs: int, strategy: Strategy
     return m, n
 
 
-def plan_conv_exact_scalar(wl: ConvWorkload, p_macs: int,
-                           controller: Controller) -> tuple[int, int]:
-    """Frozen pre-vectorization exact search (the seed's per-candidate Python
-    loop). Kept as the parity oracle for the property tests and as the
-    baseline the ``dse`` benchmark section measures the argmin speedup
-    against. Do not optimise."""
-    g = wl.groups
-    mg, ng = wl.cin // g, wl.cout // g
-    budget = max(1, p_macs // (wl.k * wl.k))
-    best_mn, best_b = (1, 1), float("inf")
-    for m in range(1, min(mg, budget) + 1):
-        n = min(ng, max(1, budget // m))
-        b = sum(conv_bandwidth(wl, m, n, controller, exact_iters=True))
-        if b < best_b:
-            best_mn, best_b = (m, n), b
-    return best_mn
-
-
 def conv_exact_search_batch(workloads, p_macs: int, controller: Controller
                             ) -> list[tuple[int, int]]:
     """Vectorized exact search over a whole network in one shot: concatenate
-    every layer's candidate set, evaluate eqs (2)/(3) on the flat arrays, and
-    take one segmented argmin. Bit-for-bit the scalar loop's choices (first
-    minimum wins, as strict ``<`` does in the loop)."""
+    every layer's candidate set, evaluate `conv_terms` on the flat arrays, and
+    take one segmented argmin. Bit-for-bit the seed's per-candidate loop's
+    choices (first minimum wins, as strict ``<`` does in the loop)."""
     workloads = list(workloads)
     if not workloads:
         return []
@@ -191,13 +188,13 @@ def conv_exact_search_batch(workloads, p_macs: int, controller: Controller
         return np.repeat(np.fromiter((fn(w) for w in workloads), np.int64,
                                      len(workloads)), lengths)
 
-    b_i, b_o = _bandwidth_terms(
-        mg=per_wl(lambda w: w.cin // w.groups),
-        ng=per_wl(lambda w: w.cout // w.groups),
-        in_pref=per_wl(lambda w: w.wi * w.hi * w.cin),
-        out_pref=per_wl(lambda w: w.wo * w.ho * w.cout),
-        m=m, n=n, controller=controller, exact_iters=True)
-    cost = (b_i + b_o).astype(np.float64)
+    network = types.SimpleNamespace(
+        cin=per_wl(lambda w: w.cin), cout=per_wl(lambda w: w.cout),
+        groups=per_wl(lambda w: w.groups),
+        in_acts=per_wl(lambda w: w.in_acts),
+        out_acts=per_wl(lambda w: w.out_acts),
+        word_bytes=per_wl(lambda w: w.word_bytes))
+    cost = conv_terms(network, m, n, controller, exact_iters=True).total
 
     # Segmented first-minimum argmin: stable sort by (segment, cost, position)
     # then pick each segment's first row.

@@ -1,8 +1,8 @@
 """The paper's traffic model generalized to VMEM-budget GEMM blocking.
 
-Single implementation of the block-shape search; ``core.partitioner`` is a
-thin shim over this module. The objective is the paper's first-order traffic
-model with the constraint swapped (eq 1's P MACs -> a VMEM byte budget):
+Single implementation of the GEMM traffic model (`matmul_terms`) and the
+block-shape search. The objective is the paper's first-order traffic model
+with the constraint swapped (eq 1's P MACs -> a VMEM byte budget):
 
   paper:  K^2 * m * n                                           <= P MACs
   here :  2 * (bytes(bm,bk) + bytes(bk,bn) + 2 * acc_bytes(bm,bn))  <= VMEM budget
@@ -32,12 +32,24 @@ these are the worst case over group sizes:
   A reads:  ceil(N/bn) * (M + (G - 1) * bm) * K
   B reads:  (ceil(M/bm) + G - 1) * K * N    (bk < K)
             G * K * N                       (bk = K)
+
+The accumulator memory (VMEM) is written once per k-step and read on every
+later one, under either controller:
+
+  SRAM writes:  ceil(K/bk) * M * N
+  SRAM reads:   (ceil(K/bk) - 1) * M * N
+
+Bytes weight A and B reads by the operand width, the active controller's one
+C write by the output width, and the passive controller's spills and
+read-backs by the fp32 accumulator width.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
+from typing import NamedTuple
 
 import numpy as np
 
@@ -71,29 +83,6 @@ class MatmulBlocks:
         bounds the active launch's accumulator plus its output tile."""
         return int(vmem_bytes_grid(self.bm, self.bn, self.bk, in_bytes,
                                    acc_bytes, double_buffer))
-
-
-def matmul_traffic(m: int, n: int, k: int, blocks, controller="active",
-                   groups: int = 1) -> dict[str, float]:
-    """HBM traffic in *elements* for the blocked GEMM (``groups`` > 1: the
-    grouped GEMM's worst case over group sizes, see the module docstring).
-
-    `blocks` is anything with bm/bn/bk (MatmulBlocks or a matmul Schedule);
-    `controller` coerces from the legacy strings.
-    """
-    controller = Controller.coerce(controller)
-    gi = math.ceil(m / blocks.bm) + groups - 1
-    gj = math.ceil(n / blocks.bn)
-    gk = math.ceil(k / blocks.bk)
-    a_reads = gj * m * k + gj * (groups - 1) * blocks.bm * k
-    b_reads = (groups if groups > 1 and gk == 1 else gi) * k * n
-    if controller is Controller.ACTIVE:
-        c_traffic = m * n
-    else:
-        c_traffic = (2 * gk - 1) * m * n
-    return {"a_reads": float(a_reads), "b_reads": float(b_reads),
-            "c_traffic": float(c_traffic),
-            "total": float(a_reads + b_reads + c_traffic)}
 
 
 def _aligned_candidates(dim: int, align: int, cap: int) -> list[int]:
@@ -132,71 +121,67 @@ def vmem_bytes_grid(bm, bn, bk, in_bytes: int = 2, acc_bytes: int = 4,
     return mult * ((bm * bk + bk * bn) * in_bytes + 2 * bm * bn * acc_bytes)
 
 
-def matmul_traffic_grid(m: int, n: int, k: int, bm, bn, bk,
-                        controller="active", groups: int = 1
-                        ) -> dict[str, np.ndarray]:
-    """Vectorized `matmul_traffic` over candidate block arrays; the ``total``
-    entry is bit-identical to the scalar evaluator element-for-element
-    (exact int64 arithmetic, one final float conversion)."""
+class MatmulTerms(NamedTuple):
+    """`matmul_terms`' result: the int64 count of N blocks and float64
+    words and bytes, 0-d for one schedule, one entry per candidate for
+    arrays."""
+
+    gj: np.ndarray           # N blocks: reads of each A word
+    a_reads: np.ndarray
+    b_reads: np.ndarray
+    c_traffic: np.ndarray
+    input_words: np.ndarray  # A + B reads
+    total: np.ndarray
+    sram_reads: np.ndarray
+    sram_writes: np.ndarray
+    bytes: np.ndarray
+    sram_bytes: np.ndarray   # SRAM accesses at the accumulator width
+
+    @property
+    def output_words(self) -> np.ndarray:
+        return self.c_traffic
+
+    @property
+    def read_iters(self) -> np.ndarray:
+        return self.gj
+
+    @property
+    def weight_reads(self) -> np.ndarray:
+        return self.b_reads
+
+
+def matmul_terms(wl: MatmulWorkload, bm, bn, bk, controller) -> MatmulTerms:
+    """The GEMM traffic model (module docstring) for ``wl`` under blocks
+    ``bm``/``bn``/``bk``: scalars for one schedule or candidate arrays.
+    Every count is exact int64 arithmetic with one final float64
+    conversion."""
     controller = Controller.coerce(controller)
     bm = np.asarray(bm, np.int64)
     bn = np.asarray(bn, np.int64)
     bk = np.asarray(bk, np.int64)
+    m, n, k, groups = wl.m, wl.n, wl.k, wl.groups
     gi = -(-m // bm) + (groups - 1)
     gj = -(-n // bn)
     gk = -(-k // bk)
     a_reads = gj * (m * k) + gj * ((groups - 1) * k) * bm
     b_reads = (np.where(gk == 1, groups, gi) if groups > 1 else gi) * (k * n)
+    acc = m * n
     if controller is Controller.ACTIVE:
-        c_traffic = np.full_like(a_reads, m * n)
+        c_traffic = np.full_like(a_reads, acc)
+        c_bytes = c_traffic * wl.out_bytes
     else:
-        c_traffic = (2 * gk - 1) * (m * n)
-    return {"a_reads": a_reads.astype(np.float64),
-            "b_reads": b_reads.astype(np.float64),
-            "c_traffic": c_traffic.astype(np.float64),
-            "total": (a_reads + b_reads + c_traffic).astype(np.float64)}
-
-
-def traffic_model_bytes_grid(m: int, n: int, k: int, bm, bn, bk, controller,
-                             in_bytes: int = 2, out_bytes: int = 2,
-                             acc_bytes: int = 4, groups: int = 1
-                             ) -> np.ndarray:
-    """Vectorized `traffic_model_bytes` over candidate block arrays — the one
-    dtype-weighted byte model the `repro.plan.objectives` cost functions
-    share. Passive spills move fp32 accumulators; the active final write is
-    the output dtype."""
-    controller = Controller.coerce(controller)
-    t = matmul_traffic_grid(m, n, k, bm, bn, bk, controller, groups)
-    io = (t["a_reads"] + t["b_reads"]) * in_bytes
-    if controller is Controller.ACTIVE:
-        return io + float(m * n * out_bytes)
-    gk = -(-k // np.asarray(bk, np.int64))
-    return io + ((gk - 1) * 2 + 1) * (m * n) * acc_bytes
-
-
-def plan_matmul_blocks_scalar(m: int, n: int, k: int, *, in_bytes: int = 2,
-                              acc_bytes: int = 4,
-                              vmem_budget: int = DEFAULT_VMEM_BUDGET,
-                              controller="active",
-                              max_block: int = 4096) -> MatmulBlocks:
-    """Frozen pre-vectorization exhaustive search (the seed's triple Python
-    loop). Parity oracle for the property tests and the benchmark baseline.
-    Do not optimise."""
-    controller = Controller.coerce(controller)
-    best: MatmulBlocks | None = None
-    best_t = float("inf")
-    for bm in _aligned_candidates(m, SUBLANE * 16, max_block):      # mult of 128
-        for bn in _aligned_candidates(n, LANE, max_block):
-            for bk in _aligned_candidates(k, LANE, max_block):
-                b = MatmulBlocks(bm, bn, bk)
-                if b.vmem_bytes(in_bytes, acc_bytes) > vmem_budget:
-                    continue
-                t = matmul_traffic(m, n, k, b, controller)["total"]
-                if t < best_t:
-                    best, best_t = b, t
-    if best is None:  # budget smaller than one minimal tile — take minimum
-        best = MatmulBlocks(SUBLANE * 16, LANE, LANE)
-    return best
+        c_traffic = (2 * gk - 1) * acc
+        c_bytes = c_traffic * wl.acc_bytes          # spills are fp32
+    sram_reads = (gk - 1) * acc
+    sram_writes = gk * acc
+    f64 = functools.partial(np.asarray, dtype=np.float64)
+    return MatmulTerms(
+        gj=gj, a_reads=f64(a_reads), b_reads=f64(b_reads),
+        c_traffic=f64(c_traffic), input_words=f64(a_reads + b_reads),
+        total=f64(a_reads + b_reads + c_traffic),
+        sram_reads=f64(sram_reads), sram_writes=f64(sram_writes),
+        bytes=f64((a_reads + b_reads) * wl.in_bytes + c_bytes),
+        sram_bytes=f64((sram_reads + sram_writes) * wl.acc_bytes))
 
 
 def plan_matmul_blocks(m: int, n: int, k: int, *, in_bytes: int = 2,
@@ -242,23 +227,6 @@ def conv_blocks_from_partition(m_part: int, n_part: int) -> tuple[int, int]:
     bm = max(SUBLANE, min(512, 1 << (m_part - 1).bit_length()))
     bn = max(LANE, min(512, 1 << (n_part - 1).bit_length()))
     return bm, bn
-
-
-def traffic_model_bytes(m: int, n: int, k: int, blocks, controller,
-                        in_bytes: int = 2, out_bytes: int = 2,
-                        acc_bytes: int = 4, groups: int = 1) -> float:
-    """Traffic in bytes, distinguishing in/out/accumulator element widths.
-    Passive spills move fp32 accumulators; the active final write is the
-    output dtype — an additional saving the paper's word-count model hides."""
-    controller = Controller.coerce(controller)
-    t = matmul_traffic(m, n, k, blocks, controller, groups)
-    io = (t["a_reads"] + t["b_reads"]) * in_bytes
-    if controller is Controller.ACTIVE:
-        c = m * n * out_bytes
-    else:
-        gk = math.ceil(k / blocks.bk)
-        c = ((gk - 1) * 2 + 1) * m * n * acc_bytes  # spills are fp32
-    return io + c
 
 
 def plan_gemm(wl: MatmulWorkload, vmem_budget: int, strategy: Strategy,

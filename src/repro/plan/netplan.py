@@ -50,7 +50,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import math
 from typing import Any, NamedTuple
 
 import numpy as np
@@ -59,10 +58,10 @@ from repro.errors import BudgetError, PlanError
 from repro.obs.metrics import REGISTRY, StatsCounter
 from repro.obs.trace import span
 from repro.plan import api as _api
-from repro.plan import conv_model, dse, gemm_model
+from repro.plan import conv_model, dse
 from repro.plan.graph import NetworkGraph, Node
 from repro.plan.schedule import Controller, Schedule, Strategy
-from repro.plan.traffic import TrafficReport, traffic_report
+from repro.plan.traffic import TrafficReport, terms, traffic_report
 from repro.plan.workload import ConvWorkload, MatmulWorkload
 
 # Engine-side residency buffer (bytes) available for holding inter-layer
@@ -235,23 +234,11 @@ def _node_candidates(wl, budget: int | None, strategy, controller: Controller):
 
 def _node_grid(wl, budget: int | None, strategy,
                controller: Controller) -> _NodeGrid:
-    cands, mask, kind = _node_candidates(wl, budget, strategy, controller)
-    if kind == "conv":
-        ng = wl.cout // wl.groups
-        read_iters = -(-ng // np.minimum(cands.bn, ng))
-        _, b_o = conv_model.conv_bandwidth_grid(wl, cands.bm, cands.bn,
-                                                controller, exact_iters=True)
-        fixed = np.zeros(len(cands), dtype=np.float64)
-        out_traffic = b_o
-    else:
-        t = gemm_model.matmul_traffic_grid(wl.m, wl.n, wl.k, cands.bm,
-                                           cands.bn, cands.bk, controller)
-        read_iters = -(-wl.n // np.asarray(cands.bn, np.int64))
-        fixed = t["b_reads"]
-        out_traffic = t["c_traffic"]
-    fixed = np.where(mask, fixed, np.inf)
-    return _NodeGrid(cands=cands, mask=mask, read_iters=read_iters,
-                     fixed=fixed, out_traffic=out_traffic)
+    cands, mask, _ = _node_candidates(wl, budget, strategy, controller)
+    t = terms(wl, cands.bm, cands.bn, cands.bk, controller)
+    return _NodeGrid(cands=cands, mask=mask, read_iters=t.read_iters,
+                     fixed=np.where(mask, t.weight_reads, np.inf),
+                     out_traffic=t.output_words)
 
 
 @dataclasses.dataclass(eq=False)
@@ -371,39 +358,26 @@ def _resolve_sim_objective(strategy, objective):
 # ------------------------------------------------------- analytical totals
 def _node_bus_report(wl, schedule: Schedule, spilled_in_words: int,
                      out_spilled: bool) -> TrafficReport:
-    """Residency-adjusted `TrafficReport` for one node: interconnect words
-    drop the resident shares; local (SRAM + residency buffer) accesses match
-    the per-layer meter model unchanged."""
-    if isinstance(wl, ConvWorkload):
-        b_i, b_o = conv_model.conv_bandwidth(wl, schedule.m, schedule.n,
-                                             schedule.controller,
-                                             exact_iters=True)
-        g = wl.groups
-        mg, ng = wl.cin // g, wl.cout // g
-        out_iters = math.ceil(ng / min(schedule.n, ng))
-        in_iters = math.ceil(mg / min(schedule.m, mg))
-        in_bus = float(spilled_in_words * out_iters)
-        out_bus = b_o if out_spilled else 0.0
-        sram_reads = b_i + (in_iters - 1) * wl.out_acts
-        sram_writes = float(in_iters * wl.out_acts)
-        word_bytes = wl.word_bytes
-    elif isinstance(wl, MatmulWorkload):
-        t = gemm_model.matmul_traffic(wl.m, wl.n, wl.k, schedule,
-                                      schedule.controller, wl.groups)
-        gj = math.ceil(wl.n / schedule.bn)
-        gk = math.ceil(wl.k / schedule.bk)
-        in_bus = float(spilled_in_words * gj + t["b_reads"])
-        out_bus = t["c_traffic"] if out_spilled else 0.0
-        acc = wl.m * wl.n
-        sram_reads = float((gk - 1) * acc)
-        sram_writes = float(gk * acc)
-        word_bytes = wl.in_bytes
-    else:
-        raise TypeError(f"unknown workload {type(wl).__name__}")
+    """Residency-adjusted `TrafficReport` for one node: each spilled input
+    word crosses the bus once per re-read (`_NodeGrid`'s decomposition),
+    a resident output's words stay off it; local (SRAM + residency buffer)
+    accesses match the per-layer meter model unchanged."""
+    t = _terms(wl, schedule)
+    in_bus = float(spilled_in_words * t.read_iters + t.weight_reads)
+    out_bus = float(t.output_words) if out_spilled else 0.0
+    width = wl.word_bytes if isinstance(wl, ConvWorkload) else wl.in_bytes
     total = in_bus + out_bus
     return TrafficReport(interconnect_words=total, input_words=in_bus,
-                         output_words=out_bus, sram_reads=sram_reads,
-                         sram_writes=sram_writes, bytes=total * word_bytes)
+                         output_words=out_bus,
+                         sram_reads=float(t.sram_reads),
+                         sram_writes=float(t.sram_writes),
+                         bytes=total * width)
+
+
+def _terms(wl, schedule: Schedule):
+    """The traffic model's terms for one scheduled node."""
+    return terms(wl, schedule.bm, schedule.bn, schedule.bk,
+                 schedule.controller)
 
 
 def network_report(graph: NetworkGraph, schedules: dict[str, Schedule],
@@ -1116,11 +1090,7 @@ def _assemble(graph: NetworkGraph, budget, strategy, controller: Controller,
         by_name[node.name] = np_plan
 
     def _read_iters(consumer: Node) -> int:
-        wl, sched = consumer.workload, chosen[consumer.name]
-        if isinstance(wl, ConvWorkload):
-            ng = wl.cout // wl.groups
-            return math.ceil(ng / min(sched.n, ng))
-        return math.ceil(wl.n / sched.bn)
+        return int(_terms(consumer.workload, chosen[consumer.name]).read_iters)
 
     edges = []
     for tname, prod_step, cons_steps in graph.edge_list():

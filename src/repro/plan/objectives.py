@@ -30,10 +30,10 @@ from typing import Callable, Union
 
 import numpy as np
 
-from repro.plan import conv_model, gemm_model
+from repro.plan import traffic
 from repro.plan.schedule import Controller
 from repro.plan.space import Candidates
-from repro.plan.workload import ConvWorkload, MatmulWorkload, Workload
+from repro.plan.workload import Workload
 from repro.roofline.constants import (ENERGY_PJ_INTERCONNECT_BYTE,
                                       ENERGY_PJ_SRAM_BYTE, HBM_BW,
                                       PEAK_FLOPS_BF16)
@@ -73,9 +73,8 @@ def get_objective(objective: Objective) -> ObjectiveFn:
                        f"registered: {sorted(OBJECTIVES)}") from None
 
 
-def _kind_error(fn_name: str, wl) -> TypeError:
-    return TypeError(f"objective {fn_name} got unsupported workload "
-                     f"{type(wl).__name__}")
+def _terms(wl: Workload, cands: Candidates, controller: Controller):
+    return traffic.terms(wl, cands.bm, cands.bn, cands.bk, controller)
 
 
 # --------------------------------------------------------------- interconnect
@@ -83,54 +82,18 @@ def _kind_error(fn_name: str, wl) -> TypeError:
 def interconnect_words(wl: Workload, cands: Candidates,
                        controller: Controller) -> np.ndarray:
     """Words crossing the interconnect/HBM — the paper's BW objective."""
-    if isinstance(wl, ConvWorkload):
-        b_i, b_o = conv_model.conv_bandwidth_grid(
-            wl, cands.bm, cands.bn, controller, exact_iters=True)
-        return b_i + b_o
-    if isinstance(wl, MatmulWorkload):
-        return gemm_model.matmul_traffic_grid(
-            wl.m, wl.n, wl.k, cands.bm, cands.bn, cands.bk,
-            controller, wl.groups)["total"]
-    raise _kind_error("interconnect_words", wl)
+    return _terms(wl, cands, controller).total
 
 
 # --------------------------------------------------------------- SRAM traffic
-def _conv_sram(wl: ConvWorkload, cands: Candidates, controller: Controller
-               ) -> tuple[np.ndarray, np.ndarray]:
-    """(reads, writes) at the accumulator SRAM — `plan.traffic`'s meter
-    model, vectorized. Identical for both controllers: the active controller
-    moves work off the bus, it does not remove it."""
-    b_i, _ = conv_model.conv_bandwidth_grid(
-        wl, cands.bm, cands.bn, controller, exact_iters=True)
-    g = wl.groups
-    mg = wl.cin // g
-    m_eff = np.minimum(np.asarray(cands.bm, np.int64), mg)
-    in_iters = -(-mg // m_eff)
-    out_acts = wl.out_acts
-    reads = b_i + (in_iters - 1) * out_acts
-    writes = (in_iters * out_acts).astype(np.float64)
-    return reads, writes
-
-
-def _matmul_sram(wl: MatmulWorkload, cands: Candidates
-                 ) -> tuple[np.ndarray, np.ndarray]:
-    gk = -(-wl.k // np.asarray(cands.bk, np.int64))
-    acc = wl.m * wl.n
-    return (((gk - 1) * acc).astype(np.float64),
-            (gk * acc).astype(np.float64))
-
-
 @register_objective("sram_accesses")
 def sram_accesses(wl: Workload, cands: Candidates,
                   controller: Controller) -> np.ndarray:
-    """Total accumulator-memory accesses (reads + writes)."""
-    if isinstance(wl, ConvWorkload):
-        reads, writes = _conv_sram(wl, cands, controller)
-        return reads + writes
-    if isinstance(wl, MatmulWorkload):
-        reads, writes = _matmul_sram(wl, cands)
-        return reads + writes
-    raise _kind_error("sram_accesses", wl)
+    """Total accumulator-memory accesses (reads + writes). Identical for both
+    controllers: the active controller moves work off the bus, it does not
+    remove it."""
+    t = _terms(wl, cands, controller)
+    return t.sram_reads + t.sram_writes
 
 
 # ------------------------------------------------------------ weighted energy
@@ -140,21 +103,9 @@ def energy_bytes(wl: Workload, cands: Candidates,
     """Energy-weighted bytes (pJ): interconnect bytes at ~8x the cost of SRAM
     bytes. Unlike pure word counts this penalizes the passive controller's
     read-back twice (once on the bus, once in SRAM)."""
-    if isinstance(wl, ConvWorkload):
-        ic_bytes = interconnect_words(wl, cands, controller) * wl.word_bytes
-        reads, writes = _conv_sram(wl, cands, controller)
-        sram_bytes = (reads + writes) * wl.word_bytes
-    elif isinstance(wl, MatmulWorkload):
-        ic_bytes = gemm_model.traffic_model_bytes_grid(
-            wl.m, wl.n, wl.k, cands.bm, cands.bn, cands.bk, controller,
-            in_bytes=wl.in_bytes, out_bytes=wl.out_bytes,
-            acc_bytes=wl.acc_bytes, groups=wl.groups)
-        reads, writes = _matmul_sram(wl, cands)
-        sram_bytes = (reads + writes) * wl.acc_bytes
-    else:
-        raise _kind_error("energy_bytes", wl)
-    return (ic_bytes * ENERGY_PJ_INTERCONNECT_BYTE
-            + sram_bytes * ENERGY_PJ_SRAM_BYTE)
+    t = _terms(wl, cands, controller)
+    return (t.bytes * ENERGY_PJ_INTERCONNECT_BYTE
+            + t.sram_bytes * ENERGY_PJ_SRAM_BYTE)
 
 
 # ---------------------------------------------------------- roofline latency
@@ -165,15 +116,5 @@ def roofline_latency(wl: Workload, cands: Candidates,
     Compute time is schedule-invariant, so this objective is flat wherever
     the workload is compute-bound and reduces to byte-minimization where it
     is bandwidth-bound — exactly the regime the paper targets."""
-    if isinstance(wl, ConvWorkload):
-        flops = 2.0 * wl.macs
-        nbytes = interconnect_words(wl, cands, controller) * wl.word_bytes
-    elif isinstance(wl, MatmulWorkload):
-        flops = float(wl.flops)
-        nbytes = gemm_model.traffic_model_bytes_grid(
-            wl.m, wl.n, wl.k, cands.bm, cands.bn, cands.bk, controller,
-            in_bytes=wl.in_bytes, out_bytes=wl.out_bytes,
-            acc_bytes=wl.acc_bytes, groups=wl.groups)
-    else:
-        raise _kind_error("roofline_latency", wl)
-    return np.maximum(flops / PEAK_FLOPS_BF16, nbytes / HBM_BW)
+    nbytes = _terms(wl, cands, controller).bytes
+    return np.maximum(float(wl.flops) / PEAK_FLOPS_BF16, nbytes / HBM_BW)

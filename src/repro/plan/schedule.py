@@ -1,9 +1,7 @@
 """Typed schedule vocabulary for the unified planning API.
 
-`Strategy` and `Controller` replace the stringly-typed ``strategy=``/
-``controller=`` arguments of the legacy ``core.bwmodel`` / ``core.partitioner``
-entry points; both coerce from the legacy strings so call sites migrate
-incrementally.
+`Strategy` and `Controller` type the ``strategy=``/``controller=``
+arguments; both coerce from their string values.
 
 `Schedule` is the single execution-schedule type consumed by every backend:
 the AMC simulator, the Pallas kernels, and the traffic model. It subsumes
@@ -67,9 +65,9 @@ class Controller(enum.Enum):
 class Partition:
     """Channel partition: m input maps x n output maps per iteration.
 
-    Legacy type kept for the ``core.bwmodel`` shims; new code should carry a
-    full `Schedule` (which round-trips via ``Schedule.from_partition`` /
-    ``Schedule.as_partition``).
+    The paper's own vocabulary (``core.amc`` executes one); a `Schedule`
+    carries the same blocks and round-trips via ``Schedule.from_partition`` /
+    ``Schedule.as_partition``.
     """
 
     m: int
@@ -95,6 +93,8 @@ class Schedule:
     controller: Controller = Controller.PASSIVE
 
     def __post_init__(self):
+        object.__setattr__(self, "controller",
+                           Controller.coerce(self.controller))
         if self.kind not in ("conv", "matmul"):
             raise ValueError(f"unknown schedule kind {self.kind!r}")
         if self.bm < 1 or self.bn < 1 or self.bk < 0:
@@ -132,7 +132,7 @@ class Schedule:
     @classmethod
     def from_blocks(cls, blocks, controller: Controller | str = Controller.ACTIVE
                     ) -> "Schedule":
-        """From a legacy ``core.partitioner.MatmulBlocks`` (duck-typed)."""
+        """From a ``gemm_model.MatmulBlocks`` (duck-typed)."""
         return cls(kind="matmul", bm=blocks.bm, bn=blocks.bn, bk=blocks.bk,
                    controller=Controller.coerce(controller))
 

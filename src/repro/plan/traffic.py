@@ -2,20 +2,19 @@
 schedule) pair — interconnect words (the paper's "BW"), local-memory
 (SRAM/VMEM) accesses, and dtype-weighted bytes.
 
-The conv numbers reproduce the analytical model of eqs (2)/(3) and mirror the
-instrumented AMC simulation (``core.amc``) access-for-access, which is what
-``amc.run_partitioned_conv`` cross-validates against. The matmul numbers are
-the blocked-GEMM model of ``plan.gemm_model`` (validated against the Pallas
-kernels' ``hbm_traffic_bytes``).
+Every number comes from the workload kind's one evaluator, `terms`:
+`conv_model.conv_terms` (eqs (2)/(3), mirrored access-for-access by the
+instrumented AMC simulation, ``core.amc``) or `gemm_model.matmul_terms`
+(the blocked-GEMM model, proved against the Pallas kernels' traced words by
+`repro.check.dataflow`).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
 
 from repro.plan import conv_model, gemm_model
-from repro.plan.schedule import Controller, Schedule
+from repro.plan.schedule import Schedule
 from repro.plan.workload import ConvWorkload, MatmulWorkload, Workload
 
 
@@ -49,46 +48,40 @@ class TrafficReport:
         return dataclasses.asdict(self)
 
 
+def terms(wl: Workload, bm, bn, bk, controller, exact_iters: bool = True
+          ) -> "conv_model.ConvTerms | gemm_model.MatmulTerms":
+    """The traffic model of ``wl``'s kind under blocks ``bm``/``bn``/``bk``
+    (``bk`` unused for convs; ``exact_iters`` unused for GEMMs): scalars for
+    one schedule or candidate arrays."""
+    if isinstance(wl, ConvWorkload):
+        return conv_model.conv_terms(wl, bm, bn, controller, exact_iters)
+    if isinstance(wl, MatmulWorkload):
+        return gemm_model.matmul_terms(wl, bm, bn, bk, controller)
+    raise TypeError(f"unknown workload type {type(wl).__name__}")
+
+
+def _report(t) -> TrafficReport:
+    return TrafficReport(interconnect_words=float(t.total),
+                         input_words=float(t.input_words),
+                         output_words=float(t.output_words),
+                         sram_reads=float(t.sram_reads),
+                         sram_writes=float(t.sram_writes),
+                         bytes=float(t.bytes))
+
+
 def conv_traffic(wl: ConvWorkload, schedule: Schedule,
                  exact_iters: bool = True) -> TrafficReport:
     """Report for a partitioned conv (defaults to ceil iteration counts, the
     executable semantics; pass exact_iters=False for the paper's real-valued
     M/m convention)."""
-    b_i, b_o = conv_model.conv_bandwidth(wl, schedule.m, schedule.n,
-                                         schedule.controller, exact_iters)
-    g = wl.groups
-    mg = wl.cin // g
-    in_iters = math.ceil(mg / min(schedule.m, mg))
-    # Mirror of the AMC meter: every input word is read from input SRAM once
-    # per arrival; the accumulator is written every iteration and read on
-    # every non-first iteration (internally when active, over the bus when
-    # passive — same count, different interconnect charge).
-    sram_reads = b_i + (in_iters - 1) * wl.out_acts
-    sram_writes = float(in_iters * wl.out_acts)
-    total = b_i + b_o
-    return TrafficReport(interconnect_words=total, input_words=b_i,
-                         output_words=b_o, sram_reads=sram_reads,
-                         sram_writes=sram_writes,
-                         bytes=total * wl.word_bytes)
+    return _report(conv_model.conv_terms(wl, schedule.m, schedule.n,
+                                         schedule.controller, exact_iters))
 
 
 def matmul_traffic_report(wl: MatmulWorkload, schedule: Schedule) -> TrafficReport:
     """Report for a blocked GEMM under the schedule's controller."""
-    t = gemm_model.matmul_traffic(wl.m, wl.n, wl.k, schedule,
-                                  schedule.controller, wl.groups)
-    nbytes = gemm_model.traffic_model_bytes(
-        wl.m, wl.n, wl.k, schedule, schedule.controller,
-        in_bytes=wl.in_bytes, out_bytes=wl.out_bytes, acc_bytes=wl.acc_bytes,
-        groups=wl.groups)
-    gk = math.ceil(wl.k / schedule.bk)
-    acc = wl.m * wl.n
-    return TrafficReport(
-        interconnect_words=t["total"],
-        input_words=t["a_reads"] + t["b_reads"],
-        output_words=t["c_traffic"],
-        sram_reads=float((gk - 1) * acc),   # accumulator re-reads per k step
-        sram_writes=float(gk * acc),
-        bytes=nbytes)
+    return _report(gemm_model.matmul_terms(wl, schedule.bm, schedule.bn,
+                                           schedule.bk, schedule.controller))
 
 
 def traffic_report(workload: Workload, schedule: Schedule,

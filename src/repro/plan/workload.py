@@ -13,9 +13,9 @@ A `Workload` is the union of
 Both are frozen/hashable so plans can be LRU-cached on
 (workload, budget, strategy, controller).
 
-NOTE: this module must not import ``repro.core`` at module level — the legacy
-``core.bwmodel``/``core.partitioner`` modules are shims over ``repro.plan``,
-so a top-level import here would be circular. Adapters import lazily.
+NOTE: this module must not import ``repro.core`` at module level —
+``core.planner`` builds on ``repro.plan``, so a top-level import here would
+be circular. Adapters import lazily.
 """
 
 from __future__ import annotations
@@ -51,6 +51,10 @@ class ConvWorkload:
     @property
     def macs(self) -> int:
         return (self.wo * self.ho * self.cout * self.cin // self.groups) * self.k * self.k
+
+    @property
+    def flops(self) -> int:
+        return 2 * self.macs
 
     @classmethod
     def from_layer(cls, layer) -> "ConvWorkload":

@@ -1,6 +1,6 @@
 """The instrumented active-memory-controller simulation must (a) compute the
 same convolution as the jnp oracle and (b) meter exactly the traffic that the
-analytical model of bwmodel.py predicts."""
+analytical model of `repro.plan.conv_model` predicts."""
 
 import jax
 import jax.numpy as jnp
@@ -9,8 +9,8 @@ import pytest
 
 from repro.core.amc import (MemoryController, analytical_interconnect_words,
                             run_partitioned_conv)
-from repro.core.bwmodel import Partition
 from repro.core.cnn_zoo import ConvLayer
+from repro.plan import Partition
 
 
 def _oracle_conv(x, w, stride, pad):

@@ -1,4 +1,6 @@
-"""Unit + property tests for the paper's bandwidth model (Section II/III)."""
+"""Unit + property tests for the paper's bandwidth model (Section II/III),
+through `repro.plan`: `conv_model.conv_terms`, ``plan()`` and the network
+totals."""
 
 import math
 
@@ -10,11 +12,26 @@ try:
 except ModuleNotFoundError:   # optional dep: fall back to the vendored stub
     from _hypothesis_stub import given, settings, st
 
-from repro.core import bwmodel
-from repro.core.bwmodel import Partition, layer_bandwidth, partition_layer
+from repro import plan
 from repro.core.cnn_zoo import PAPER_CNNS, PAPER_TABLE3, ConvLayer, get_cnn
+from repro.plan import Partition
+from repro.plan.conv_model import conv_terms
 
 P_VALUES = (512, 1024, 2048, 4096, 8192, 16384)
+STRATEGIES = ("max_input", "max_output", "equal", "paper_opt", "exact_opt")
+
+
+def layer_bandwidth(layer, part, controller="passive", exact_iters=False):
+    """(B_i, B_o) for one layer under a partition (the paper's M/m unless
+    ``exact_iters``)."""
+    t = conv_terms(plan.ConvWorkload.from_layer(layer), part.m, part.n,
+                   controller, exact_iters)
+    return float(t.b_i), float(t.b_o)
+
+
+def partition_layer(layer, p_macs, strategy="paper_opt"):
+    return plan.plan(plan.ConvWorkload.from_layer(layer), p_macs, strategy,
+                     "passive").schedule
 
 
 def _layer(m=64, n=128, k=3, wi=28, wo=28, groups=1):
@@ -45,7 +62,7 @@ def test_active_controller_removes_readback():
 def test_eq7_formula():
     l = _layer(m=64, n=128, k=3, wi=56, wo=56)
     for p in P_VALUES:
-        m_star = bwmodel.optimal_m_realvalued(l, p)
+        m_star = plan.optimal_m_realvalued(plan.ConvWorkload.from_layer(l), p)
         assert m_star == pytest.approx(
             math.sqrt(2 * l.wo * l.ho * p / (l.wi * l.hi * l.k ** 2)))
 
@@ -59,7 +76,7 @@ def test_eq7_is_stationary_point():
         return (l.wi * l.hi * l.cin * l.cout * l.k ** 2 * m / p
                 + l.wo * l.ho * l.cout * (2 * l.cin / m - 1))
 
-    m_star = bwmodel.optimal_m_realvalued(l, p)
+    m_star = plan.optimal_m_realvalued(plan.ConvWorkload.from_layer(l), p)
     eps = 1e-4
     deriv = (bw(m_star + eps) - bw(m_star - eps)) / (2 * eps)
     assert abs(deriv) < 1e-3 * bw(m_star)
@@ -70,7 +87,7 @@ def test_mac_constraint_eq1():
     for net in PAPER_CNNS:
         for layer in get_cnn(net):
             for p in (512, 2048, 16384):
-                for strat in bwmodel.STRATEGIES:
+                for strat in STRATEGIES:
                     part = partition_layer(layer, p, strat)
                     if layer.k ** 2 <= p:  # eq (1) satisfiable
                         assert part.macs(layer.k) <= p, (net, layer.name, strat)
@@ -83,12 +100,12 @@ def test_table3_exact_matches():
     (documented in EXPERIMENTS.md)."""
     exact = {"alexnet", "squeezenet", "googlenet", "resnet18", "mnasnet"}
     for net in exact:
-        ours = bwmodel.min_bandwidth(get_cnn(net)) / 1e6
+        ours = plan.min_network_traffic(net) / 1e6
         assert ours == pytest.approx(PAPER_TABLE3[net], abs=5e-4), net
 
 
 def test_table3_mobilenet_v1_matches_paper():
-    ours = bwmodel.min_bandwidth(get_cnn("mobilenetv1")) / 1e6
+    ours = plan.min_network_traffic("mobilenetv1") / 1e6
     assert ours == pytest.approx(PAPER_TABLE3["mobilenet"], rel=0.01)
 
 
@@ -97,10 +114,10 @@ def test_table3_mobilenet_v1_matches_paper():
 def test_table1_ordering(net, p):
     """Paper's central Table-I claim: this-work <= equal <= max strategies."""
     kw = dict(paper_convention=True)
-    opt = bwmodel.network_table(net, p, "paper_opt", **kw)
-    eq = bwmodel.network_table(net, p, "equal", **kw)
-    mi = bwmodel.network_table(net, p, "max_input", **kw)
-    mo = bwmodel.network_table(net, p, "max_output", **kw)
+    opt = plan.network_traffic(net, p, "paper_opt", **kw)
+    eq = plan.network_traffic(net, p, "equal", **kw)
+    mi = plan.network_traffic(net, p, "max_input", **kw)
+    mo = plan.network_traffic(net, p, "max_output", **kw)
     assert opt <= eq * 1.001
     assert opt <= mi * 1.001
     assert opt <= mo * 1.001
@@ -108,24 +125,24 @@ def test_table1_ordering(net, p):
 
 @pytest.mark.parametrize("net", PAPER_CNNS)
 def test_bw_decreases_with_macs_and_approaches_min(net):
-    layers = get_cnn(net)
+    layers = plan.conv_workloads(net)
     prev = float("inf")
     for p in P_VALUES:
-        b = bwmodel.network_bandwidth(layers, p, "exact_opt")
+        b = plan.network_traffic(layers, p, "exact_opt")
         assert b <= prev * 1.001
         prev = b
-    huge = bwmodel.network_bandwidth(layers, 1 << 34, "exact_opt")
-    assert huge == pytest.approx(bwmodel.min_bandwidth(layers), rel=1e-6)
+    huge = plan.network_traffic(layers, 1 << 34, "exact_opt")
+    assert huge == pytest.approx(plan.min_network_traffic(layers), rel=1e-6)
 
 
 @pytest.mark.parametrize("net", PAPER_CNNS)
 @pytest.mark.parametrize("p", P_VALUES)
 def test_table2_active_saving_bands(net, p):
     """Fig. 2 claim: active controller saves; at P=512 savings 19-42%."""
-    passive = bwmodel.network_table(net, p, "paper_opt", "passive",
-                                    paper_convention=True)
-    active = bwmodel.network_table(net, p, "paper_opt", "active",
+    passive = plan.network_traffic(net, p, "paper_opt", "passive",
                                    paper_convention=True)
+    active = plan.network_traffic(net, p, "paper_opt", "active",
+                                  paper_convention=True)
     saving = 100 * (1 - active / passive)
     assert 0.0 < saving < 50.0
     if p == 512:
@@ -136,9 +153,9 @@ def test_exact_opt_beats_first_order():
     """Beyond-paper: integer-exact search never loses to the snapped eq (7)."""
     for net in PAPER_CNNS:
         for p in (512, 2048, 16384):
-            exact = bwmodel.network_bandwidth(get_cnn(net), p, "exact_opt")
-            paper = bwmodel.network_bandwidth(get_cnn(net), p, "paper_opt",
-                                              exact_iters=True)
+            exact = plan.network_traffic(net, p, "exact_opt")
+            paper = plan.network_traffic(net, p, "paper_opt",
+                                         exact_iters=True)
             assert exact <= paper * 1.0001, (net, p)
 
 

@@ -4,9 +4,8 @@ Covers: every planner output verifying clean (property tests over random
 valid workloads for both plan() and plan_graph()), one deliberately corrupted
 input per diagnostic code (>= 10 distinct codes), the Pallas pre-flight gate
 rejecting a malformed launch *before* any kernel compiles, the checked=True
-modes on plan()/plan_graph()/simulate(), the AST lint rules on synthetic
-sources plus the repo itself being lint-clean, and the regression pin for the
-`hbm_traffic_bytes` delegation the lint forced.
+modes on plan()/plan_graph()/simulate(), and the AST lint rules on synthetic
+sources plus the repo itself being lint-clean.
 """
 
 import ast
@@ -42,7 +41,7 @@ def test_code_registry_is_stable():
     assert {"RPC001", "RPC002", "RPC003", "RPC004", "RPC005", "RPC006",
             "RPC007", "RPC008", "RPC010", "RPC011", "RPC012", "RPC013",
             "RPC020", "RPC021", "RPC022", "RPC030", "RPC031", "RPC032",
-            "RPC033", "RPL100", "RPL101", "RPL102", "RPL110"} <= set(CODES)
+            "RPC033", "RPL100", "RPL101", "RPL102"} <= set(CODES)
     assert CODES["RPC001"].slug == "mac-budget-exceeded"
     assert CODES["RPC010"].slug == "words-bytes-mix"
     assert CODES["RPC020"].slug == "residency-overlap"
@@ -534,14 +533,6 @@ def test_rpl102_words_bytes_cross_assign():
     assert _codes(_lint_src("out_words = in_bytes * 2\n")) == {"RPL100"}
 
 
-def test_rpl110_deprecated_import():
-    got = _lint_src("from repro.core import bwmodel\n")
-    assert _codes(got) == {"RPL110"}
-    assert got[0].severity is Severity.WARNING
-    assert _codes(_lint_src("import repro.core.partitioner\n")) == {"RPL110"}
-    assert _lint_src("from repro.core import cnn_zoo\n") == []
-
-
 def test_repo_is_lint_clean():
     """Satellite 6's invariant: the shipped tree has zero lint findings."""
     assert rc.check_codebase() == []
@@ -550,8 +541,7 @@ def test_repo_is_lint_clean():
 def test_lint_rules_load_from_tools():
     rules = rlint.load_rules()
     assert {r.code for r in rules} == {"RPL100", "RPL101", "RPL102",
-                                       "RPL103", "RPL104", "RPL105",
-                                       "RPL110"}
+                                       "RPL103", "RPL104", "RPL105"}
 
 
 def test_rpl104_adhoc_wall_timing():
@@ -567,21 +557,6 @@ def test_rpl104_adhoc_wall_timing():
                      rel="src/repro/launch/planserve.py") == []
     # reading the module attribute without calling is not timing
     assert _lint_src("f = time.perf_counter\n") == []
-
-
-# ------------------------------------------------ latent-violation pin
-def test_hbm_traffic_bytes_delegates_to_gemm_model():
-    """RPL100 fix: kernels/psum_matmul must reuse the one GEMM byte model,
-    not carry a private copy of it."""
-    from repro.kernels.psum_matmul import hbm_traffic_bytes
-    from repro.plan.gemm_model import MatmulBlocks, traffic_model_bytes
-    for (m, n, k) in [(512, 512, 512), (300, 700, 900), (128, 4096, 64)]:
-        for ctrl in ("active", "passive"):
-            got = hbm_traffic_bytes(m, n, k, bm=128, bn=256, bk=128,
-                                    controller=ctrl)
-            want = traffic_model_bytes(m, n, k, MatmulBlocks(128, 256, 128),
-                                       ctrl, acc_bytes=4)
-            assert got == want
 
 
 # ------------------------------------------------------------------- CLI

@@ -1,15 +1,14 @@
 """Tests for the `repro.plan.dse` design-space exploration API.
 
-Covers: property tests pinning the vectorized grid evaluators to the scalar
-eqs-(1-7) implementations bit-for-bit (randomized workloads, groups,
-controllers), the batched network search vs per-layer plans, custom
+Covers: property tests pinning the vectorized evaluators and searches to
+the frozen seed model (``_seed_model``) bit-for-bit (randomized workloads,
+groups, controllers), the batched network search vs per-layer plans, custom
 Objective/Strategy registration driving ``plan()``/``sweep()`` end-to-end,
-sweep/pareto semantics, the AMC cross-validation of sweep rows, the
-deprecation-shim warnings, and the dtype-threaded VMEM footprints.
+sweep/pareto semantics, the AMC cross-validation of sweep rows, and the
+dtype-threaded VMEM footprints.
 """
 
 import dataclasses
-import warnings
 
 import numpy as np
 import pytest
@@ -19,8 +18,9 @@ try:
 except ModuleNotFoundError:   # optional dep: fall back to the vendored stub
     from _hypothesis_stub import given, settings, st
 
+import _seed_model as seed
 from repro import plan
-from repro.core import amc, bwmodel, partitioner
+from repro.core import amc
 from repro.core.cnn_zoo import get_cnn
 from repro.plan import conv_model, dse, gemm_model, objectives
 from repro.plan.schedule import Controller, Schedule, Strategy
@@ -47,15 +47,20 @@ CTRL_ST = st.sampled_from(list(Controller))
 @settings(max_examples=100, deadline=None)
 @given(wl=conv_wl_st, p=P_ST, ctrl=CTRL_ST, exact=st.booleans())
 def test_property_conv_grid_matches_scalar(wl, p, ctrl, exact):
-    """`conv_bandwidth_grid` == scalar `conv_bandwidth` on every candidate of
-    the exact space, exact float equality (eqs 2/3, both controllers, both
-    iteration conventions, grouped convs included)."""
+    """`conv_terms` over the exact space == the seed's scalar eqs (2)/(3) on
+    every candidate, and == its own one-schedule evaluation, exact float
+    equality (both controllers, both iteration conventions, grouped convs
+    included)."""
     m, n = conv_model.conv_exact_candidates(wl, p)
-    b_i, b_o = conv_model.conv_bandwidth_grid(wl, m, n, ctrl, exact_iters=exact)
+    t = conv_model.conv_terms(wl, m, n, ctrl, exact_iters=exact)
     for i in range(len(m)):
-        si, so = conv_model.conv_bandwidth(wl, int(m[i]), int(n[i]), ctrl,
-                                           exact_iters=exact)
-        assert b_i[i] == si and b_o[i] == so, (wl, int(m[i]), int(n[i]))
+        si, so = seed.layer_bandwidth(wl, seed.SeedPartition(int(m[i]),
+                                                             int(n[i])),
+                                      ctrl.value, exact_iters=exact)
+        one = conv_model.conv_terms(wl, int(m[i]), int(n[i]), ctrl,
+                                    exact_iters=exact)
+        assert t.b_i[i] == si == one.b_i and t.b_o[i] == so == one.b_o, (
+            wl, int(m[i]), int(n[i]))
 
 
 @settings(max_examples=100, deadline=None)
@@ -64,7 +69,8 @@ def test_property_vectorized_exact_matches_scalar_loop(wl, p, ctrl):
     """The masked-argmin exact search picks the same (m, n) as the frozen
     per-candidate scalar loop — including its first-minimum tie-break."""
     sched = plan.plan(wl, p, "exact_opt", ctrl).schedule
-    assert (sched.m, sched.n) == conv_model.plan_conv_exact_scalar(wl, p, ctrl)
+    want = seed.partition_layer(wl, p, "exact_opt", ctrl.value)
+    assert (sched.m, sched.n) == (want.m, want.n)
 
 
 @settings(max_examples=50, deadline=None)
@@ -72,16 +78,16 @@ def test_property_vectorized_exact_matches_scalar_loop(wl, p, ctrl):
        ctrl=CTRL_ST)
 def test_property_gemm_vectorized_matches_scalar_loop(m, n, k, ctrl):
     """Vectorized aligned-block search == frozen triple loop, and the traffic
-    grid matches the scalar evaluator on every candidate."""
+    grid matches the seed's scalar model on every candidate."""
     got = gemm_model.plan_matmul_blocks(m, n, k, controller=ctrl)
-    want = gemm_model.plan_matmul_blocks_scalar(m, n, k, controller=ctrl)
-    assert got == want
+    want = seed.plan_matmul_blocks(m, n, k, controller=ctrl.value)
+    assert (got.bm, got.bn, got.bk) == (want.bm, want.bn, want.bk)
     bm, bn, bk = gemm_model.aligned_block_candidates(m, n, k)
-    total = gemm_model.matmul_traffic_grid(m, n, k, bm, bn, bk, ctrl)["total"]
+    wl = plan.MatmulWorkload(m=m, n=n, k=k)
+    total = gemm_model.matmul_terms(wl, bm, bn, bk, ctrl).total
     for i in range(0, len(bm), max(1, len(bm) // 7)):   # spot-check the grid
-        blocks = gemm_model.MatmulBlocks(int(bm[i]), int(bn[i]), int(bk[i]))
-        assert total[i] == gemm_model.matmul_traffic(m, n, k, blocks,
-                                                     ctrl)["total"]
+        blocks = seed.SeedBlocks(int(bm[i]), int(bn[i]), int(bk[i]))
+        assert total[i] == seed.matmul_traffic(m, n, k, blocks, ctrl.value)
 
 
 @settings(max_examples=20, deadline=None)
@@ -91,7 +97,8 @@ def test_property_batch_matches_per_layer(p, ctrl):
     wls = plan.conv_workloads("squeezenet")
     batch = conv_model.conv_exact_search_batch(wls, p, ctrl)
     for wl, mn in zip(wls, batch):
-        assert mn == conv_model.plan_conv_exact_scalar(wl, p, ctrl)
+        want = seed.partition_layer(wl, p, "exact_opt", ctrl.value)
+        assert mn == (want.m, want.n)
 
 
 def test_plan_many_batches_exact_conv():
@@ -147,9 +154,7 @@ def test_custom_objective_drives_plan_and_sweep_end_to_end():
 
     @plan.register_objective(obj_name)
     def input_only(wl, cands, controller):
-        b_i, _ = conv_model.conv_bandwidth_grid(wl, cands.bm, cands.bn,
-                                                controller, exact_iters=True)
-        return b_i
+        return conv_model.conv_terms(wl, cands.bm, cands.bn, controller).b_i
 
     try:
         dse.register_strategy(strat_name, conv=dse.StrategySpec(
@@ -161,11 +166,9 @@ def test_custom_objective_drives_plan_and_sweep_end_to_end():
         # minimizing B_i alone maximizes n: no exact-space candidate has
         # strictly lower input traffic than the chosen schedule
         m, n = conv_model.conv_exact_candidates(wl, 2048)
-        b_i, _ = conv_model.conv_bandwidth_grid(wl, m, n, Controller.PASSIVE,
-                                                exact_iters=True)
-        chosen_b_i = conv_model.conv_bandwidth(
-            wl, p.schedule.m, p.schedule.n, Controller.PASSIVE,
-            exact_iters=True)[0]
+        b_i = conv_model.conv_terms(wl, m, n, Controller.PASSIVE).b_i
+        chosen_b_i = conv_model.conv_terms(
+            wl, p.schedule.m, p.schedule.n, Controller.PASSIVE).b_i
         assert chosen_b_i == b_i.min()
         # plan() accepts and caches the custom strategy name
         assert plan.plan(wl, 2048, strat_name, "passive") is p
@@ -248,31 +251,6 @@ def test_pareto_frontier_budget_vs_traffic():
                        for f in frontier)
 
 
-# ----------------------------------------------------------- deprecation shims
-def test_bwmodel_shim_warns_once_per_entry_point():
-    layers = get_cnn("alexnet")
-    bwmodel._WARNED.clear()
-    with pytest.warns(DeprecationWarning, match="bwmodel.min_bandwidth"):
-        bwmodel.min_bandwidth(layers)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")          # second call: no warning
-        bwmodel.min_bandwidth(layers)
-        # a different entry point still gets its own (single) warning
-        with pytest.raises(DeprecationWarning,
-                           match="bwmodel.partition_layer"):
-            bwmodel.partition_layer(layers[0], 2048)
-
-
-def test_partitioner_shim_warns_once_per_entry_point():
-    partitioner._WARNED.clear()
-    with pytest.warns(DeprecationWarning,
-                      match="partitioner.plan_matmul_blocks"):
-        partitioner.plan_matmul_blocks(512, 512, 512)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        partitioner.plan_matmul_blocks(512, 512, 512)
-
-
 # --------------------------------------------------- dtype-threaded VMEM bytes
 def test_vmem_bytes_threads_workload_dtypes():
     wl8 = plan.MatmulWorkload(m=4096, n=4096, k=4096, in_bytes=1, out_bytes=1,
@@ -305,7 +283,7 @@ def test_exact_opt_parity_below_one_mac_column():
     the seed loop's initial best did — plan(), plan_many() and the frozen
     scalar oracle all agree."""
     wl = _wl(mg=16, ng=16, k=5, wi=8, wo=8)
-    assert conv_model.plan_conv_exact_scalar(wl, 16, Controller.PASSIVE) == (1, 1)
+    assert seed.partition_layer(wl, 16, "exact_opt") == seed.SeedPartition(1, 1)
     p = plan.plan(wl, 16, "exact_opt", "passive")
     assert (p.schedule.m, p.schedule.n) == (1, 1)
     [pm] = plan.plan_many([wl], 16, "exact_opt", "passive")

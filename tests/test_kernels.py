@@ -13,7 +13,9 @@ except ModuleNotFoundError:   # optional dep: fall back to the vendored stub
     from _hypothesis_stub import given, settings, st
 
 from repro.kernels import ops, ref
-from repro.kernels.psum_matmul import hbm_traffic_bytes, psum_matmul
+from repro.kernels.psum_matmul import psum_matmul
+from repro.plan.gemm_model import matmul_terms
+from repro.plan.workload import MatmulWorkload
 
 jax.config.update("jax_enable_x64", False)
 
@@ -67,9 +69,9 @@ def test_active_passive_identical_results():
 
 def test_traffic_model_active_saves():
     m = n = k = 2048
-    kw = dict(bm=256, bn=256, bk=256)
-    ta = hbm_traffic_bytes(m, n, k, controller="active", **kw)
-    tp = hbm_traffic_bytes(m, n, k, controller="passive", **kw)
+    wl = MatmulWorkload(m=m, n=n, k=k)
+    ta = matmul_terms(wl, 256, 256, 256, "active").bytes
+    tp = matmul_terms(wl, 256, 256, 256, "passive").bytes
     assert ta < tp
     # with gk=8 reduction steps, passive pays (2*8-1)*4 bytes vs 2 bytes out
     assert tp - ta == ((2 * 8 - 1) * 4 - 2) * m * n

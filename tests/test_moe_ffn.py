@@ -19,6 +19,7 @@ import pytest
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+import _seed_model as seed
 from repro import plan
 from repro.check.dataflow import analyze_launch, matmul_dataflow
 from repro.check.diagnostics import errors
@@ -29,8 +30,7 @@ from repro.kernels.psum_matmul import (grouped_matmul_launch_plan,
                                        grouped_weight_fetches,
                                        psum_grouped_matmul)
 from repro.kernels.ref import moe_layer_ref
-from repro.plan.gemm_model import (matmul_traffic, matmul_traffic_grid,
-                                   plan_matmul_blocks_scalar)
+from repro.plan.gemm_model import matmul_terms
 
 KEY = jax.random.PRNGKey(15)
 
@@ -161,7 +161,7 @@ def test_an_ungrouped_plan_is_unchanged(m, n, k, controller):
     wl = plan.MatmulWorkload(m=m, n=n, k=k)
     assert wl == plan.MatmulWorkload(m=m, n=n, k=k, groups=1)
     got = plan.plan(wl, strategy="exhaustive_vmem", controller=controller)
-    want = plan_matmul_blocks_scalar(m, n, k, controller=controller)
+    want = seed.plan_matmul_blocks(m, n, k, controller=controller)
     assert (got.schedule.bm, got.schedule.bn, got.schedule.bk) == (
         want.bm, want.bn, want.bk)
     gi, gj = -(-m // want.bm), -(-n // want.bn)
@@ -181,15 +181,15 @@ def test_grouped_traffic_by_hand(m, k, n, blocks, weight_reads):
     reads its expert's weight; with one K block, each expert's weight is
     read once. 63 partial tiles re-read bm rows of x."""
     bm, bn, bk = blocks
-    t = matmul_traffic(m, n, k, _schedule("active", *blocks), "active", 64)
-    assert t["a_reads"] == (n // bn) * (m + 63 * bm) * k
-    assert t["b_reads"] == weight_reads * k * n
-    assert t["c_traffic"] == m * n
+    wl = plan.MatmulWorkload(m=m, n=n, k=k, groups=64)
+    t = matmul_terms(wl, bm, bn, bk, "active")
+    assert t.a_reads == (n // bn) * (m + 63 * bm) * k
+    assert t.b_reads == weight_reads * k * n
+    assert t.c_traffic == m * n
     for controller in ("active", "passive"):
-        grid = matmul_traffic_grid(m, n, k, [bm], [bn], [bk], controller, 64)
-        assert {key: float(v[0]) for key, v in grid.items()} == \
-            matmul_traffic(m, n, k, _schedule(controller, *blocks),
-                           controller, 64)
+        grid = matmul_terms(wl, [bm], [bn], [bk], controller)
+        one = matmul_terms(wl, bm, bn, bk, controller)
+        assert all(g.shape == (1,) and g[0] == o for g, o in zip(grid, one))
 
 
 @pytest.mark.parametrize("controller", ["active", "passive"])
@@ -205,14 +205,14 @@ def test_the_planner_reads_each_experts_weight_once(controller):
         assert (s.bm, s.bn, s.bk) == want, name
         wl = plan.MatmulWorkload(m=12288, k=k, n=n, groups=64)
         p = plan.plan(wl, strategy="exhaustive_vmem", controller=controller)
-        t = matmul_traffic(12288, n, k, s, controller, 64)
-        assert t["b_reads"] == 64 * k * n
-        assert p.traffic.interconnect_words == t["total"]
+        t = matmul_terms(wl, s.bm, s.bn, s.bk, controller)
+        assert t.b_reads == 64 * k * n
+        assert p.traffic.interconnect_words == t.total
     up = plan.plan(plan.MatmulWorkload(m=12288, k=2048, n=1408, groups=64),
                    strategy="exhaustive_vmem", controller="active")
-    t = matmul_traffic(12288, 1408, 2048, up.schedule, "active", 64)
-    assert up.traffic.bytes == 2 * (t["a_reads"] + t["b_reads"]
-                                    + t["c_traffic"])
+    s = up.schedule
+    t = matmul_terms(up.workload, s.bm, s.bn, s.bk, "active")
+    assert up.traffic.bytes == 2 * (t.a_reads + t.b_reads + t.c_traffic)
 
 
 def _walk_weight_fetches(sizes, *, k, n, bm, bn, bk):
@@ -248,8 +248,8 @@ def test_the_model_bounds_the_weight_reads_the_launch_makes(sizes, blocks):
     k = n = 256
     fetches, live, (gn, _, gk) = _walk_weight_fetches(
         sizes, k=k, n=n, bm=bm, bn=bn, bk=bk)
-    model = matmul_traffic(sum(sizes), n, k, _schedule("active", *blocks),
-                           "active", len(sizes))["b_reads"] / (bk * bn)
+    wl = plan.MatmulWorkload(m=sum(sizes), n=n, k=k, groups=len(sizes))
+    model = matmul_terms(wl, bm, bn, bk, "active").b_reads / (bk * bn)
     experts = sum(1 for r in sizes if r)
     if gk == 1:
         assert fetches == experts * gn <= model

@@ -1,4 +1,5 @@
-"""Tests for the TPU-side generalization: VMEM-budget matmul block planning."""
+"""Tests for the TPU-side generalization: VMEM-budget matmul block planning
+(`repro.plan.gemm_model`)."""
 
 import pytest
 try:
@@ -7,9 +8,10 @@ try:
 except ModuleNotFoundError:   # optional dep: fall back to the vendored stub
     from _hypothesis_stub import given, settings, st
 
-from repro.core.partitioner import (DEFAULT_VMEM_BUDGET, MatmulBlocks,
-                                    first_order_block, matmul_traffic,
-                                    plan_matmul_blocks, traffic_model_bytes)
+from repro.plan.gemm_model import (DEFAULT_VMEM_BUDGET, MatmulBlocks,
+                                   first_order_block, matmul_terms,
+                                   plan_matmul_blocks)
+from repro.plan.workload import MatmulWorkload
 
 GEMMS = [
     (4096, 4096, 4096),
@@ -18,6 +20,11 @@ GEMMS = [
     (512, 512, 512),
     (128, 128, 128),
 ]
+
+
+def matmul_traffic(m, n, k, b, controller):
+    return matmul_terms(MatmulWorkload(m=m, n=n, k=k), b.bm, b.bn, b.bk,
+                        controller)
 
 
 @pytest.mark.parametrize("m,n,k", GEMMS)
@@ -30,8 +37,8 @@ def test_planned_blocks_fit_budget_and_align(m, n, k):
 @pytest.mark.parametrize("m,n,k", GEMMS)
 def test_active_beats_passive_traffic(m, n, k):
     b = plan_matmul_blocks(m, n, k)
-    ta = matmul_traffic(m, n, k, b, "active")["total"]
-    tp = matmul_traffic(m, n, k, b, "passive")["total"]
+    ta = matmul_traffic(m, n, k, b, "active").total
+    tp = matmul_traffic(m, n, k, b, "passive").total
     assert ta <= tp
     if k > b.bk:  # more than one reduction step -> strict saving
         assert ta < tp
@@ -41,8 +48,8 @@ def test_active_beats_passive_traffic(m, n, k):
 def test_exact_search_beats_first_order(m, n, k):
     exact = plan_matmul_blocks(m, n, k)
     fo = first_order_block(m, n, k)
-    te = matmul_traffic(m, n, k, exact, "active")["total"]
-    tf = matmul_traffic(m, n, k, fo, "active")["total"]
+    te = matmul_traffic(m, n, k, exact, "active").total
+    tf = matmul_traffic(m, n, k, fo, "active").total
     assert te <= tf * 1.0001
 
 
@@ -50,7 +57,7 @@ def test_traffic_floor_is_touch_each_operand_once():
     m, n, k = 1024, 1024, 1024
     b = plan_matmul_blocks(m, n, k)
     t = matmul_traffic(m, n, k, b, "active")
-    assert t["total"] >= m * k + k * n + m * n
+    assert t.total >= m * k + k * n + m * n
 
 
 @settings(max_examples=100, deadline=None)
@@ -60,7 +67,7 @@ def test_property_budget_respected(m, n, k):
     b = plan_matmul_blocks(m, n, k)
     assert b.vmem_bytes() <= DEFAULT_VMEM_BUDGET
     t = matmul_traffic(m, n, k, b, "active")
-    assert t["total"] >= m * k + k * n + m * n - 1
+    assert t.total >= m * k + k * n + m * n - 1
 
 
 @settings(max_examples=50, deadline=None)
@@ -71,16 +78,16 @@ def test_property_more_vmem_never_more_traffic(m, n, k, budget):
     """Monotonicity: growing the budget (paper: adding MACs) can only help."""
     small = plan_matmul_blocks(m, n, k, vmem_budget=budget)
     large = plan_matmul_blocks(m, n, k, vmem_budget=budget * 2)
-    ts = matmul_traffic(m, n, k, small, "active")["total"]
-    tl = matmul_traffic(m, n, k, large, "active")["total"]
+    ts = matmul_traffic(m, n, k, small, "active").total
+    tl = matmul_traffic(m, n, k, large, "active").total
     assert tl <= ts * 1.0001
 
 
 def test_bytes_model_passive_spills_are_fp32():
     m = n = k = 2048
     b = MatmulBlocks(256, 256, 256)
-    active_bytes = traffic_model_bytes(m, n, k, b, "active")
-    passive_bytes = traffic_model_bytes(m, n, k, b, "passive")
+    active_bytes = matmul_traffic(m, n, k, b, "active").bytes
+    passive_bytes = matmul_traffic(m, n, k, b, "passive").bytes
     gk = k // b.bk
     io = (gk and (n // b.bn) * m * k + (m // b.bm) * k * n) * 2
     assert active_bytes == io + m * n * 2

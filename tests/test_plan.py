@@ -268,6 +268,69 @@ def test_traffic_report_breakdown_consistency():
                                 "bytes"}
 
 
+_READER_WORKLOADS = [
+    plan.ConvWorkload(name="dense", cin=64, cout=128, k=3, wi=28, hi=28,
+                      wo=28, ho=28),
+    plan.ConvWorkload(name="depthwise", cin=96, cout=96, k=3, wi=28, hi=28,
+                      wo=14, ho=14, stride=2, groups=96),
+    plan.MatmulWorkload(m=2048, n=8960, k=1536, name="dense"),
+    plan.MatmulWorkload(m=12288, n=1408, k=2048, name="grouped", groups=64),
+]
+
+
+def _one_node_graph(wl):
+    """``wl`` alone, fed by one input tensor holding its whole input."""
+    if isinstance(wl, plan.ConvWorkload):
+        return plan.NetworkGraph.from_layers([wl])
+    tensors = {"x": plan.Tensor("x", channels=wl.k, h=1, w=wl.m,
+                                word_bytes=wl.in_bytes),
+               "y": plan.Tensor("y", channels=wl.n, h=1, w=wl.m,
+                                word_bytes=wl.out_bytes)}
+    return plan.NetworkGraph(name="one", nodes=(
+        plan.Node(name="input", op="input", ins=(), out="x"),
+        plan.Node(name=wl.name, op="matmul", ins=("x",), out="y",
+                  workload=wl)), tensors=tensors)
+
+
+@pytest.mark.parametrize("controller", ["passive", "active"])
+@pytest.mark.parametrize("wl", _READER_WORKLOADS,
+                         ids=["conv", "conv-depthwise", "gemm",
+                              "gemm-groups64"])
+def test_readers_agree_with_the_evaluator(wl, controller):
+    """Every reader of the traffic model reports the one evaluator's numbers
+    at a planned schedule: the `TrafficReport`, the word and SRAM
+    objectives, the verifier, and the network report with nothing resident
+    (a grouped GEMM's partial row tiles are outside the residency model)."""
+    import repro.check as rc
+    from repro.plan.space import Candidates
+    from repro.plan.traffic import terms
+    conv = isinstance(wl, plan.ConvWorkload)
+    p = plan.plan(wl, strategy="exact_opt" if conv else "exhaustive_vmem",
+                  controller=controller)
+    s = p.schedule
+    t = terms(wl, s.bm, s.bn, s.bk, s.controller)
+    r = plan.traffic_report(wl, s)
+    assert r == p.traffic
+    assert r.as_dict() == {
+        "interconnect_words": t.total, "input_words": t.input_words,
+        "output_words": t.output_words, "sram_reads": t.sram_reads,
+        "sram_writes": t.sram_writes, "bytes": t.bytes}
+    assert all(type(v) is float for v in r.as_dict().values())
+    cands = Candidates.single(s.kind, s.bm, s.bn, s.bk)
+    ctrl = s.controller
+    assert plan.get_objective("interconnect_words")(wl, cands, ctrl)[0] \
+        == r.interconnect_words
+    assert plan.get_objective("sram_accesses")(wl, cands, ctrl)[0] \
+        == r.sram_reads + r.sram_writes
+    assert rc.check_traffic(wl, s, r) == []
+    if conv or wl.groups == 1:
+        g = _one_node_graph(wl)
+        net = plan.network_report(g, {g.workload_nodes[0].name: s})
+        words = [f for f in r.as_dict() if f != "bytes"]
+        assert {f: getattr(net, f) for f in words} == \
+            {f: getattr(r, f) for f in words}
+
+
 def test_traffic_report_kind_mismatch():
     wl = plan.MatmulWorkload(m=256, n=256, k=256)
     with pytest.raises(ValueError, match="matmul workload"):

@@ -32,23 +32,18 @@ RPL105  no bare ``except:``, and no ``except Exception: pass``, anywhere
         ``repro.errors``) exists so failures are dispatched on by *type* —
         a swallowed exception is an un-observable fault. Harness/script
         roots (``benchmarks/``, ``examples/``, ``tools/``) are exempt.
-RPL110  ``repro.core.bwmodel`` / ``repro.core.partitioner`` are deprecation
-        shims; new code imports ``repro.plan``. Only the shim package itself
-        may touch them.
 """
 
 from repro.check.lint import (adhoc_timing_rule, bare_except_rule,
-                              cross_assign_rule, deprecated_import_rule,
-                              magic_energy_rule, raw_byte_arith_rule,
-                              raw_pallas_rule)
+                              cross_assign_rule, magic_energy_rule,
+                              raw_byte_arith_rule, raw_pallas_rule)
 
 #: modules allowed to convert words -> bytes
 BYTE_MODEL_MODULES = (
-    "src/repro/plan/traffic.py",       # conv TrafficReport construction
+    "src/repro/plan/conv_model.py",    # conv byte model
     "src/repro/plan/gemm_model.py",    # VMEM working sets + GEMM byte model
     "src/repro/plan/graph.py",         # Tensor.nbytes
     "src/repro/plan/netplan.py",       # residency-adjusted bus reports
-    "src/repro/plan/objectives.py",    # energy/bytes DSE objectives
     "src/repro/plan/schedule.py",      # Schedule.vmem_bytes
     "src/repro/plan/workload.py",      # workload footprint helpers
     "src/repro/sim/*",                 # the simulator prices bytes
@@ -65,5 +60,4 @@ RULES = [
     adhoc_timing_rule(("src/repro/obs/*", "benchmarks/*",
                        "src/repro/launch/planserve.py")),
     bare_except_rule(("benchmarks/*", "examples/*", "tools/*")),
-    deprecated_import_rule(("src/repro/core/*",)),
 ]
